@@ -152,3 +152,76 @@ def sched_epoch_state(scenario_name="hetero-16rack", max_jobs=10):
     topo = spec.topology()
     jobs = spec.trace(topo)[:max_jobs]
     return ClusterState(topology=topo, now_ms=0.0, running=jobs, pending=[])
+
+
+def sharded_fill_case(racks, window_ms):
+    """The largest real rebuild-shaped water-filling union of the
+    contended ``rack-scaling-{racks}`` state, captured while the
+    incremental re-solver advances ``window_ms``.
+
+    Returns ``(sim, union, comps, build_rows)``: ``union`` holds the
+    captured ``(JR, binding, demand, live, mask)``, ``comps`` its
+    independent components, and ``build_rows()`` their
+    :func:`repro.cluster.shard.batched_fill` rows.  The capture-time
+    member caps are restored on ``sim``, so the sharded fill, the fused
+    host fill (``sim._wf_fill_core(JR, binding, demand, live)``) and the
+    from-scratch solve all see the same instance.
+    """
+    import numpy as np
+
+    from repro.cluster import FluidNetworkSim, contended_snapshot
+    from repro.engine.scenarios import get_scenario
+
+    spec = get_scenario(f"rack-scaling-{racks}")
+    topo = spec.topology()
+    jobs = contended_snapshot(topo, lambda: spec.trace(topo), tenants=2)
+    sim = FluidNetworkSim(topo, vectorized=True, incremental=True)
+    sim.configure(jobs)
+    cap: dict = {}
+    orig_rebuild = sim._wf_rebuild
+
+    def probing_rebuild(comm_mask, caps_now):
+        st = orig_rebuild(comm_mask, caps_now)
+        rows_all, cols_all = sim._inc.flat_pairs
+        bpair = st["binding"][cols_all] & comm_mask[rows_all]
+        JR = np.unique(rows_all[bpair])
+        if JR.size > cap.get("n", 0):
+            cap.update(
+                n=JR.size, JR=JR, mask=comm_mask.copy(),
+                binding=st["binding"].copy(),
+                demand=st["demand"].copy(), live=st["live"].copy(),
+                caps=sim._cap_now.copy(),
+            )
+        return st
+
+    sim._wf_rebuild = probing_rebuild
+    sim.advance(window_ms)
+    sim._wf_rebuild = orig_rebuild
+    if not cap:
+        raise RuntimeError(
+            f"no rebuild-shaped fill captured at {racks} racks over "
+            f"the {window_ms:g}ms window"
+        )
+    # sim._cap_now has drifted past the capture point by the end of the
+    # advance: restore the capture-time snapshot
+    sim._cap_now = cap["caps"]
+    union = (cap["JR"], cap["binding"], cap["demand"], cap["live"], cap["mask"])
+    JR, binding, demand, _, _ = union
+    comps = sim._wf_components(JR, binding)
+    cap_l = sim._inc.capacities
+
+    def build_rows():
+        rows = []
+        for mem, lnks in comps:
+            eff = np.where(
+                demand[lnks] > cap_l[lnks] + 1e-9,
+                sim.congested_efficiency, 1.0,
+            )
+            rows.append((
+                sim._cap_now[mem],
+                sim._inc.sub_incidence(mem, lnks),
+                cap_l[lnks] * eff,
+            ))
+        return rows
+
+    return sim, union, comps, build_rows

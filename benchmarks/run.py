@@ -603,78 +603,21 @@ def _fluid_shard_bench():
     """
     import numpy as np
 
-    from repro.cluster import FluidNetworkSim, contended_snapshot
     from repro.cluster import shard as shard_mod
-    from repro.engine.scenarios import get_scenario
 
-    from .common import timed
+    from .common import sharded_fill_case, timed
 
     ndev = shard_mod.device_count()
 
     for racks, window_ms in ((256, 1_200.0), (1024, 350.0)):
-        spec = get_scenario(f"rack-scaling-{racks}")
-        topo = spec.topology()
-        jobs = contended_snapshot(topo, lambda: spec.trace(topo), tenants=2)
-        sim = FluidNetworkSim(topo, vectorized=True, incremental=True)
-        sim.configure(jobs)
-        # capture the largest rebuild-shaped fill of the advance window:
-        # (comm mask, binding, demand, live) at the solve that dirtied
-        # the most members
-        cap: dict = {}
-        orig_rebuild = sim._wf_rebuild
-
-        def probing_rebuild(comm_mask, caps_now):
-            st = orig_rebuild(comm_mask, caps_now)
-            rows_all, cols_all = sim._inc.flat_pairs
-            bpair = st["binding"][cols_all] & comm_mask[rows_all]
-            JR = np.unique(rows_all[bpair])
-            if JR.size > cap.get("n", 0):
-                cap.update(
-                    n=JR.size, JR=JR, mask=comm_mask.copy(),
-                    binding=st["binding"].copy(),
-                    demand=st["demand"].copy(), live=st["live"].copy(),
-                    caps=sim._cap_now.copy(),
-                )
-            return st
-
-        sim._wf_rebuild = probing_rebuild
-        sim.advance(window_ms)
-        sim._wf_rebuild = orig_rebuild
-        if not cap:
-            raise RuntimeError(
-                f"no rebuild-shaped fill captured at {racks} racks over "
-                f"the {window_ms:g}ms window"
-            )
-        # replay the captured problem exactly: every path below
-        # (sharded, sequential, fused, from-scratch) reads member caps
-        # from sim._cap_now, which has drifted past the capture point by
-        # the end of the advance — restore the capture-time snapshot so
-        # all four solve the same instance
-        sim._cap_now = cap["caps"]
-        JR, binding = cap["JR"], cap["binding"]
-        demand, live = cap["demand"], cap["live"]
-        comps = sim._wf_components(JR, binding)
+        sim, union, comps, build_rows = sharded_fill_case(racks, window_ms)
+        JR, binding, demand, live, mask = union
         if len(comps) < shard_mod.MIN_COMPONENTS:
             raise RuntimeError(
                 f"captured fill at {racks} racks has only {len(comps)} "
                 f"components — below the sharding threshold; the bench "
                 f"needs a component batch to measure"
             )
-        cap_l = sim._inc.capacities
-
-        def build_rows():
-            rows = []
-            for mem, lnks in comps:
-                eff = np.where(
-                    demand[lnks] > cap_l[lnks] + 1e-9,
-                    sim.congested_efficiency, 1.0,
-                )
-                rows.append((
-                    sim._cap_now[mem],
-                    sim._inc.sub_incidence(mem, lnks),
-                    cap_l[lnks] * eff,
-                ))
-            return rows
 
         rows = build_rows()
         # warm the jit caches for every bucket shape on every path
@@ -709,7 +652,7 @@ def _fluid_shard_bench():
             rates_q[mem] = vq
         rates_f = np.zeros(n)
         rates_f[JR] = fused
-        scratch, _ = sim._solve_alloc(cap["mask"])
+        scratch, _ = sim._solve_alloc(mask)
         band = dict(rtol=1e-9, atol=1e-9)
         ok_fused = np.allclose(rates_b[JR], rates_f[JR], **band)
         ok_seq = np.allclose(rates_q[JR], rates_b[JR], **band)

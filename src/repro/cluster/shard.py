@@ -13,8 +13,8 @@ components as *rows of a batch*:
   (members, links) shape so the jit cache stays small and stable,
 - every bucket dispatches as ONE ``vmap``-batched fill, and
 - with more than one device the bucket's row axis is split across
-  ``jax.devices()`` with ``shard_map`` (transparent single-device
-  fallback: the same jitted fill without the mesh).
+  ``jax.devices()`` with ``jax.shard_map`` (on one device: the same
+  jitted fill without the mesh).
 
 Padding invariants (see docs/architecture.md "Device sharding"):
 
@@ -52,13 +52,10 @@ _MIN_LINKS = 8
 
 
 def device_count() -> int:
-    """Host-visible device count (1 when jax is unavailable)."""
-    try:
-        import jax
+    """Host-visible device count."""
+    import jax
 
-        return len(jax.devices())
-    except Exception:  # pragma: no cover - jax is a hard dep in practice
-        return 1
+    return len(jax.devices())
 
 
 def _pow2ceil(x: int) -> int:
@@ -177,19 +174,14 @@ def _bucket_fill(ndev: int):
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # pragma: no cover - newer jax moved it
-        from jax.shard_map import shard_map  # type: ignore[no-redef]
-
     mesh = Mesh(np.array(jax.devices()[:ndev]), axis_names=("rows",))
     spec = P("rows")
-    sharded = shard_map(
+    sharded = jax.shard_map(
         batched,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded)
 
@@ -204,9 +196,9 @@ def batched_fill(rows, ndev: int | None = None):
     vector for row ``i`` and ``stats`` is a :class:`ShardStats`.
 
     ``ndev`` overrides the device count (tests use 1 to pin the
-    single-device fallback and assert device-count invariance).
+    single-device path and assert device-count invariance).
     """
-    from jax.experimental import enable_x64
+    import jax
 
     if ndev is None:
         ndev = device_count()
@@ -222,7 +214,7 @@ def batched_fill(rows, ndev: int | None = None):
         buckets.setdefault(key, []).append(i)
 
     out: list[np.ndarray | None] = [None] * len(rows)
-    with enable_x64():
+    with jax.enable_x64(True):
         for (mpad, lpad), members in sorted(buckets.items()):
             r = len(members)
             rpad = -(-r // ndev) * ndev if ndev > 1 else r

@@ -457,16 +457,12 @@ def _batched_excess(
         stats.bytes_returned += l * a * 4
         stats.bytes_matrix += l * a * 4
     if _kernel_eligible(backend, a):
-        try:
-            from repro.kernels.circle_score import ops as _cs_ops
+        from repro.kernels.circle_score import ops as _cs_ops
 
-            out = np.asarray(_cs_ops.circle_score(base, cand, cap, tuned=tuned))
-        except Exception:  # pragma: no cover - fallback if pallas unavailable
-            pass
-        else:
-            if stats is not None:
-                stats.launches += 1
-            return out
+        out = np.asarray(_cs_ops.circle_score(base, cand, cap, tuned=tuned))
+        if stats is not None:
+            stats.launches += 1
+        return out
     idx = _roll_index(a)                                       # (S, A)
     cap_rows = np.broadcast_to(cap.reshape(-1, 1, 1), (l, 1, 1))
     out = np.empty((l, a), dtype=np.float32)
@@ -493,24 +489,20 @@ def _batched_argmin(
     """Fused per-row rotation search: ``(best_shift, best_excess)`` per row.
 
     Device path only — returns ``None`` when the shape is not
-    kernel-eligible (or the kernel import fails) so the caller can fall
-    back to the full-matrix evaluation + host ``np.argmin``.  On success
-    only O(L) scalars left the device: ``stats.device_reduced`` counts the
-    call and ``bytes_returned`` grows by the reduced result size instead
-    of the ``(L, A)`` matrix.
+    kernel-eligible, so the caller takes the full-matrix evaluation + host
+    ``np.argmin``; a kernel that fails raises.  Only O(L) scalars leave
+    the device: ``stats.device_reduced`` counts the call and
+    ``bytes_returned`` grows by the reduced result size instead of the
+    ``(L, A)`` matrix.
     """
     l, a = np.asarray(base).shape
     if not _kernel_eligible(backend, a):
         return None
-    try:
-        from repro.kernels.circle_score import ops as _cs_ops
+    from repro.kernels.circle_score import ops as _cs_ops
 
-        idx, val = _cs_ops.circle_score_argmin(
-            base, cand, capacity, valid, tuned=tuned
-        )
-        idx, val = np.asarray(idx), np.asarray(val)
-    except Exception:  # pragma: no cover - fallback if pallas unavailable
-        return None
+    idx, val = _cs_ops.circle_score_argmin(
+        base, cand, capacity, valid, tuned=tuned
+    )
     if stats is not None:
         stats.device_reduced += 1
         stats.launches += 1
@@ -528,26 +520,19 @@ def _batched_argmin_ragged(
     *,
     stats: BatchStats | None = None,
     tuned: bool = True,
-) -> tuple[np.ndarray, np.ndarray] | None:
+) -> tuple[np.ndarray, np.ndarray]:
     """Ragged fused rotation search: mixed angle counts, ONE launch.
 
     ``base`` / ``cand`` are packed ``(L, W)`` rows (row ``l`` real in
     ``[:num_angles[l]]``, zero above).  The caller has already partitioned
-    rows by kernel eligibility, so this only returns ``None`` when the
-    kernel import itself fails (pallas unavailable) — the caller then
-    falls back to the grouped full-matrix evaluation.
+    rows by kernel eligibility; a kernel that fails (or rejects its
+    inputs with ``ValueError``) raises.
     """
-    try:
-        from repro.kernels.circle_score import ops as _cs_ops
+    from repro.kernels.circle_score import ops as _cs_ops
 
-        idx, val = _cs_ops.circle_score_ragged_argmin(
-            base, cand, capacity, valid, num_angles, tuned=tuned
-        )
-        idx, val = np.asarray(idx), np.asarray(val)
-    except ValueError:
-        raise  # input-validation rejections must not become silent fallbacks
-    except Exception:  # pragma: no cover - fallback if pallas unavailable
-        return None
+    idx, val = _cs_ops.circle_score_ragged_argmin(
+        base, cand, capacity, valid, num_angles, tuned=tuned
+    )
     if stats is not None:
         _account_ragged(stats, base.shape, num_angles)
         stats.bytes_returned += idx.nbytes + val.nbytes
@@ -579,17 +564,11 @@ def _batched_segmin(
     l, a = np.asarray(base).shape
     if not _kernel_eligible(backend, a):
         return None
-    try:
-        from repro.kernels.circle_score import ops as _cs_ops
+    from repro.kernels.circle_score import ops as _cs_ops
 
-        acc, row, shift, best = _cs_ops.circle_score_segmin(
-            base, cand, capacity, valid, seg_ids, init_best, tuned=tuned
-        )
-        acc, row, shift, best = (
-            np.asarray(acc), np.asarray(row), np.asarray(shift), np.asarray(best)
-        )
-    except Exception:  # pragma: no cover - fallback if pallas unavailable
-        return None
+    acc, row, shift, best = _cs_ops.circle_score_segmin(
+        base, cand, capacity, valid, seg_ids, init_best, tuned=tuned
+    )
     if stats is not None:
         stats.device_reduced += 1
         stats.launches += 1
@@ -609,25 +588,17 @@ def _batched_segmin_ragged(
     *,
     stats: BatchStats | None = None,
     tuned: bool = True,
-) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray] | None:
+) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """Ragged fused search + segmented acceptance scan: ONE launch per
     chunk, whatever mix of angle counts the chunk's problems carry (see
-    :func:`_batched_segmin` for the segment semantics).  Returns ``None``
-    only when the kernel import fails."""
-    try:
-        from repro.kernels.circle_score import ops as _cs_ops
+    :func:`_batched_segmin` for the segment semantics).  A kernel that
+    fails raises."""
+    from repro.kernels.circle_score import ops as _cs_ops
 
-        acc, row, shift, best = _cs_ops.circle_score_ragged_segmin(
-            base, cand, capacity, valid, num_angles, seg_ids, init_best,
-            tuned=tuned,
-        )
-        acc, row, shift, best = (
-            np.asarray(acc), np.asarray(row), np.asarray(shift), np.asarray(best)
-        )
-    except ValueError:
-        raise  # input-validation rejections must not become silent fallbacks
-    except Exception:  # pragma: no cover - fallback if pallas unavailable
-        return None
+    acc, row, shift, best = _cs_ops.circle_score_ragged_segmin(
+        base, cand, capacity, valid, num_angles, seg_ids, init_best,
+        tuned=tuned,
+    )
     if stats is not None:
         _account_ragged(stats, base.shape, num_angles)
         stats.bytes_returned += acc.nbytes + row.nbytes + shift.nbytes + best.nbytes
@@ -646,13 +617,10 @@ def _account_ragged(
     packed width up to a power-of-two multiple of the lane size), so
     ``pad_fraction`` reports what actually shipped.
     """
-    l, w = shape
-    try:
-        from repro.kernels.circle_score.ops import bucket_width
+    from repro.kernels.circle_score.ops import bucket_width
 
-        wl = bucket_width(w)
-    except Exception:  # pragma: no cover - pallas unavailable
-        wl = w
+    l, w = shape
+    wl = bucket_width(w)
     stats.device_reduced += 1
     stats.launches += 1
     stats.ragged_rows += l
@@ -831,7 +799,7 @@ def _solve_grids_batched(
             p for p in probs if _kernel_eligible(backend, p.circle.num_angles)
         ]
         if kernel_probs:
-            _solve_grids_ragged(kernel_probs, backend, stats, tuned)
+            _solve_grids_ragged(kernel_probs, stats, tuned)
         probs = [
             p for p in probs if not _kernel_eligible(backend, p.circle.num_angles)
         ]
@@ -873,7 +841,6 @@ def _apply_segmin(
 
 def _solve_grids_ragged(
     probs: Sequence[_GridProblem],
-    backend: str,
     stats: BatchStats,
     tuned: bool = True,
 ) -> None:
@@ -908,19 +875,7 @@ def _solve_grids_ragged(
             base, cand, caps, valid, widths, seg_ids, init,
             stats=stats, tuned=tuned,
         )
-        if reduced is not None:
-            _apply_segmin(segs, pending, reduced)
-        else:  # pragma: no cover - pallas unavailable: grouped full-matrix
-            by_angles: dict[int, list[int]] = {}
-            for r, (p, _, _) in enumerate(pending):
-                by_angles.setdefault(p.circle.num_angles, []).append(r)
-            for a, rows in by_angles.items():
-                ex = _batched_excess(
-                    base[rows][:, :a], cand[rows][:, :a], caps[rows],
-                    backend=backend, stats=stats, tuned=tuned,
-                )
-                for r, row_ex in zip(rows, ex):
-                    pending[r][0].update(pending[r][1], row_ex)
+        _apply_segmin(segs, pending, reduced)
         pending.clear()
 
     for p in probs:
@@ -1128,9 +1083,8 @@ def _solve_descent_batched(
                 for s, (b, _), row in zip(group, rows, ex):
                     s.apply(j, b, row)
 
-    def step_ragged(group: list[_DescentState], j: int) -> list[_DescentState]:
-        """One ragged launch for the step's kernel-eligible rows; returns
-        the states a failed kernel import pushes back to the grouped path."""
+    def step_ragged(group: list[_DescentState], j: int) -> None:
+        """One ragged launch for the step's kernel-eligible rows."""
         rows = [s.job_row(j) for s in group]
         widths = np.array([s.circle.num_angles for s in group], dtype=np.int32)
         w = int(widths.max())
@@ -1141,17 +1095,13 @@ def _solve_descent_batched(
             cand[r, : c.shape[0]] = c
         caps = np.array([s.capacity for s in group], dtype=np.float32)
         valid = np.array([s.grids[j] for s in group], dtype=np.int32)
-        reduced = _batched_argmin_ragged(
+        s_new, _ = _batched_argmin_ragged(
             base, cand, caps, valid, widths, stats=stats, tuned=tuned
         )
-        if reduced is None:  # pragma: no cover - pallas unavailable
-            return group
         stats.batched_calls += 1
         stats.descent_rows += len(group)
-        s_new, _ = reduced
         for s, (b, _), sn in zip(group, rows, s_new):
             s.apply_shift(j, b, int(sn))
-        return []
 
     for trial in range(_COORD_DESCENT_SEEDS):
         live = [s for s in states if not s.done]
@@ -1178,7 +1128,7 @@ def _solve_descent_batched(
                         if not _kernel_eligible(backend, s.circle.num_angles)
                     ]
                     if eligible:
-                        grouped = grouped + step_ragged(eligible, j)
+                        step_ragged(eligible, j)
                 if grouped:
                     step_grouped(grouped, j)
             for s in sweeping:

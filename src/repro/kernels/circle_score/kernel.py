@@ -33,10 +33,14 @@ Ragged row layout and masking invariants (see docs/architecture.md):
 
   * the angle axis is padded to the batch-wide lane width ``AP`` (a
     multiple of :data:`LANE_MULTIPLE`); ``base`` is zero beyond ``A_l``;
-  * the candidate ships as a *periodic* buffer
-    ``cc[l, u] = cand[l, (u − AP) mod A_l]`` of width ``2·AP``, so the
-    roll by any shift ``s`` is one dynamic slice at the row-independent
-    start ``AP − s`` — no in-kernel gathers, any mix of periods;
+  * the kernel builds, once per row block, a ``(BL, 2·AP)`` buffer ``cc``
+    with ``cc[l, AP + j] = cand[l, j]`` and ``cc[l, AP − A_l + j] =
+    cand[l, j]`` for ``j < A_l`` — two periods of every row's candidate
+    meeting at lane ``AP`` (:func:`_periodic_buffer`: a log-depth barrel
+    of static lane rolls, no gathers, any mix of periods).  The roll by
+    any shift ``s < A_l`` is then ``pltpu.roll(cc, s)[:, AP:]`` — one
+    lane rotate by the row-independent ``s`` and one static, aligned
+    slice — and it reads ``cc`` only inside that two-period window;
   * per-shift excess terms at angles ``α ≥ A_l`` are masked to exactly
     ``0.0`` before the row reduction, and shifts ``s ≥ valid[l]`` are
     masked to ``+inf`` before the tournament — padded angles and
@@ -57,11 +61,12 @@ evaluated prefix is guaranteed to contain its first zero shift, which
 the tournament selects exactly like ``np.argmin`` over the full window.
 
 TPU mapping: the circle rows live in VMEM (A ≤ ~2k angles ⇒ a (BL, AP)
-f32 tile is ≤ 1 MiB); rolls are realized as dynamic slices of the
-periodic (BL, 2·AP) buffer — no gathers — the chunk's shift evaluations
-are independent (pipelineable; the only carried state is the (BL, 1)
-champion pair) and both reductions (fold sum, tournament argmin) are
-log-depth.
+f32 tile is ≤ 1 MiB); rolls are lane rotates of the (BL, 2·AP) buffer
+(``pltpu.roll``, which Mosaic lowers for a traced shift — a dynamic
+slice at the unaligned start ``AP − s`` it refuses), the chunk's shift
+evaluations are independent (pipelineable; the only carried state is the
+(BL, 1) champion pair) and both reductions (fold sum, tournament argmin)
+are log-depth.  Every store is lane-aligned.
 """
 
 from __future__ import annotations
@@ -71,6 +76,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # 8-row blocks amortize poorly; 32 measured ~1.5-2x faster for both kernel
 # variants on large batches (and is still one VREG sublane tile on TPU).
@@ -170,38 +176,72 @@ def _tournament_argmin(
     return vals, idx
 
 
-def _circle_score_kernel(a: int, base_ref, cc_ref, cap_ref, out_ref):
+def _periodic_buffer(cand: jax.Array, na: jax.Array) -> jax.Array:
+    """``(BL, AP)`` candidate rows → the ``(BL, 2·AP)`` roll buffer ``cc``.
+
+    ``cc = [left | cand]`` where ``left`` is each row rotated right by its
+    own ``AP − A_l``, so ``cc[l, AP − A_l + j] = cand[l, j]`` for ``j <
+    A_l``: two periods of the row meet at lane ``AP``.  The row-dependent
+    rotation is a barrel of static lane rolls (bit ``b`` of ``AP − A_l``
+    selects a roll by ``2**b``), which Mosaic lowers without gathers.
+    Outside the two-period window ``cc`` holds other candidate values;
+    every read there is masked (see the module docstring).
+    """
+    bl, ap = cand.shape
+    r = ap - na                                         # (BL, 1) in [0, AP)
+    left = cand
+    b = 1
+    while b < ap:
+        left = jnp.where((r & b) != 0, pltpu.roll(left, b, 1), left)
+        b *= 2
+    return jnp.concatenate([left, cand], axis=1)
+
+
+def _circle_score_kernel(a: int, base_ref, cand_ref, cap_ref, out_ref):
     """Full-matrix variant: ``out[:, s]`` for every shift ``s < a``.
 
     ``a`` is the shared *real* (unpadded) angle count, closed over
-    statically; ``cc_ref`` is the periodic candidate buffer (see
-    ``_prep_inputs``).  Rows use the same masked fold-sum as the ragged
-    argmin kernel, so full-matrix values and fused values are
-    bit-identical.
+    statically.  Rows use the same masked fold-sum as the ragged argmin
+    kernel, so full-matrix values and fused values are bit-identical.
+    Shifts are scored 128 at a time into a ``(BL, 128)`` tile, and each
+    tile is stored at a lane-aligned offset (Mosaic refuses single-lane
+    dynamic stores); shifts ``≥ a`` in the last tile are sliced off by
+    the wrapper.
     """
     base = base_ref[...]                                # (BL, AP)
-    cc = cc_ref[...]                                    # (BL, 2*AP)
     cap = cap_ref[...]                                  # (BL, 1) per-row
     bl, ap = base.shape
+    cc = _periodic_buffer(
+        cand_ref[...], jnp.full((bl, 1), a, jnp.int32)
+    )                                                   # (BL, 2*AP)
     # mask angles >= a to exactly 0 before the fold: the reduction then
     # sees the unpadded operands plus exact additive identities, so lane
     # padding provably cannot change a single output bit
     mask = jax.lax.broadcasted_iota(jnp.int32, (bl, ap), 1) < a
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bl, LANE_MULTIPLE), 1)
 
-    def body(s, _):
-        # rolled[α] = cand[(α − s) mod a] == cc[AP − s : 2·AP − s][:AP]
-        rolled = jax.lax.dynamic_slice(cc, (0, ap - s), (bl, ap))
-        excess = jnp.maximum(base + rolled - cap, 0.0)
-        val = _fold_sum(jnp.where(mask, excess, 0.0))   # (BL, 1)
-        pl.store(out_ref, (slice(None), pl.dslice(s, 1)), val)
+    def tile(c, _):
+        def shift(i, acc):
+            s = c * LANE_MULTIPLE + i
+            # rolled[α] = cand[(α − s) mod a] == cc[AP − s + α] for α < a
+            rolled = pltpu.roll(cc, s, 1)[:, ap:]
+            excess = jnp.maximum(base + rolled - cap, 0.0)
+            val = _fold_sum(jnp.where(mask, excess, 0.0))   # (BL, 1)
+            return jnp.where(lane == i, val, acc)
+
+        acc = jax.lax.fori_loop(
+            0, LANE_MULTIPLE, shift, jnp.zeros((bl, LANE_MULTIPLE), jnp.float32)
+        )
+        start = pl.multiple_of(c * LANE_MULTIPLE, LANE_MULTIPLE)
+        out_ref[:, pl.ds(start, LANE_MULTIPLE)] = acc
         return 0
 
-    jax.lax.fori_loop(0, a, body, 0)
+    jax.lax.fori_loop(0, pl.cdiv(a, LANE_MULTIPLE), tile, 0)
 
 
 def _circle_score_argmin_kernel(
     shift_chunk: int,
-    base_ref, cc_ref, cap_ref, valid_ref, na_ref, idx_ref, val_ref,
+    base_ref, cand_ref, cap_ref, valid_ref, na_ref, idx_ref, val_ref,
 ):
     """Ragged fused variant: per-row angle counts, chunked tournament.
 
@@ -224,11 +264,11 @@ def _circle_score_argmin_kernel(
     window), independent of which other rows share the block.
     """
     base = base_ref[...]                                # (BL, AP)
-    cc = cc_ref[...]                                    # (BL, 2*AP)
     cap = cap_ref[...]                                  # (BL, 1)
     valid = valid_ref[...]                              # (BL, 1) int32
     na = na_ref[...]                                    # (BL, 1) int32
     bl, ap = base.shape
+    cc = _periodic_buffer(cand_ref[...], na)            # (BL, 2*AP)
     mask = jax.lax.broadcasted_iota(jnp.int32, (bl, ap), 1) < na
     nvalid = jnp.max(valid)
 
@@ -241,10 +281,11 @@ def _circle_score_argmin_kernel(
         cols_v, cols_i = [], []
         for i in range(shift_chunk):                    # unrolled: no deps
             s = c + i
-            # rolled[α] = cand[(α − s) mod A] == cc[AP − s : 2·AP − s][:AP]
-            # (dynamic_slice clamps s ≥ AP starts; those shifts are ≥ valid
-            # and masked to +inf below, so the clamped values never matter)
-            rolled = jax.lax.dynamic_slice(cc, (0, ap - s), (bl, ap))
+            # rolled[α] = cand[(α − s) mod A] == cc[AP − s + α] for α < A
+            # (shifts s ≥ A read outside the two-period window; they are
+            # ≥ valid and masked to +inf below, so those values never
+            # matter)
+            rolled = pltpu.roll(cc, s, 1)[:, ap:]
             excess = jnp.maximum(base + rolled - cap, 0.0)
             val = _fold_sum(jnp.where(mask, excess, 0.0))   # (BL, 1)
             cols_v.append(jnp.where(s < valid, val, jnp.inf))
@@ -269,52 +310,33 @@ def _circle_score_argmin_kernel(
 # ---------------------------------------------------------------------- #
 def _prep_inputs(
     base, cand, capacity, block_l: int, lane_pad: bool,
-    *, num_angles=None, pad_to: int | None = None,
+    *, num_angles=None,
 ):
     """Row-pad to the block size and lane-pad the angle axis.
 
-    Returns ``(base, cc, cap, na, l, a, ap)`` where ``cc`` is the
-    *periodic* candidate buffer ``cc[r, u] = cand[r, (u − AP) mod A_r]``
-    of width ``2·AP``: the roll by shift ``s`` is then the single slice
-    ``cc[:, AP − s : 2·AP − s]`` for *every* row at once, whatever mix
-    of real angle counts ``A_r ≤ a`` the batch carries.  For a uniform
-    batch (``num_angles=None`` ⇒ ``A_r = a``) this reads exactly the
-    doubled-candidate values the pre-ragged kernels used.
-
-    ``pad_to`` forces a wider lane-padded width (still masked in-kernel,
-    still bit-exact by the fold invariance) — used to bucket ragged
-    launch widths and to exercise the all-rows-padded case in tests.
+    Returns ``(base, cand, cap, na, l, a, ap)``: both operands zero-padded
+    to ``(L_pad, AP)``, per-row capacities and angle counts as ``(L_pad,
+    1)`` columns (a uniform batch, ``num_angles=None``, has ``A_r = a``
+    on every row).  The kernels build their roll buffer from ``cand`` and
+    ``na`` in VMEM (:func:`_periodic_buffer`), so nothing here gathers.
     """
     l, a = base.shape
     ap = -(-a // LANE_MULTIPLE) * LANE_MULTIPLE if lane_pad else a
-    if pad_to is not None:
-        want = -(-pad_to // LANE_MULTIPLE) * LANE_MULTIPLE if lane_pad else pad_to
-        ap = max(ap, want)
     pad_rows = (-l) % block_l
     cap = jnp.asarray(capacity, jnp.float32)
     cap = jnp.broadcast_to(cap.reshape(-1, 1) if cap.ndim else cap, (l, 1))
-    base = base.astype(jnp.float32)
-    cand = cand.astype(jnp.float32)
     if num_angles is None:
         na = jnp.full((l, 1), a, jnp.int32)
-        # uniform fast path: the periodic buffer has one shared period, so
-        # tile + static slice builds it without the per-row gather below
-        # (bit-identical — same elements, exact copies; gathers lower far
-        # worse than concat/tile on the TPU target)
-        reps = -(-ap // a)                              # ceil(AP / A)
-        off = reps * a - ap                             # phase: (−AP) mod A
-        cc = jnp.tile(cand, (1, 2 * reps))[:, off : off + 2 * ap]
     else:
         na = jnp.asarray(num_angles, jnp.int32).reshape(-1, 1)
-        u = jnp.arange(2 * ap, dtype=jnp.int32)[None, :]    # (1, 2*AP)
-        cc = jnp.take_along_axis(cand, (u - ap) % na, axis=1)
-    base = jnp.pad(base, ((0, pad_rows), (0, ap - a)))
-    cc = jnp.pad(cc, ((0, pad_rows), (0, 0)))
+    pad = ((0, pad_rows), (0, ap - a))
+    base = jnp.pad(base.astype(jnp.float32), pad)
+    cand = jnp.pad(cand.astype(jnp.float32), pad)
     cap = jnp.pad(cap, ((0, pad_rows), (0, 0)))
-    # padding rows get A = 1 (their demand is all-zero anyway) so the
-    # periodic index arithmetic stays well-defined
+    # padding rows get A = 1 (their demand is all-zero anyway) so every
+    # row's period stays in [1, AP]
     na = jnp.pad(na, ((0, pad_rows), (0, 0)), constant_values=1)
-    return base, cc, cap, na, l, a, ap
+    return base, cand, cap, na, l, a, ap
 
 
 @functools.partial(
@@ -325,8 +347,8 @@ def circle_score_pallas(
     cand: jax.Array,      # (L, A) float32
     capacity: jax.Array,  # scalar shared by all rows, or (L,)/(L, 1) per-row
     *,
+    interpret: bool,
     block_l: int = DEFAULT_BLOCK_L,
-    interpret: bool = True,
     lane_pad: bool = True,
 ) -> jax.Array:
     """Batched scoring; returns (L, A) excess sums (lower = better).
@@ -334,32 +356,34 @@ def circle_score_pallas(
     Per-row capacities let one launch cover links with different
     capacities; a scalar capacity is broadcast to every row.  Values are
     bit-identical to the fused ragged kernel (same masked fold-sum).
+    ``interpret`` is explicit: the caller decides from the backend.
     """
-    base, cc, cap, _na, l, a, ap = _prep_inputs(
+    base, cand, cap, _na, l, a, ap = _prep_inputs(
         base, cand, capacity, block_l, lane_pad
     )
     lp = base.shape[0]
+    # the output is written in whole 128-lane tiles, whatever the input
+    # lane padding
+    ow = pl.cdiv(a, LANE_MULTIPLE) * LANE_MULTIPLE
 
     out = pl.pallas_call(
         functools.partial(_circle_score_kernel, a),
         grid=(lp // block_l,),
         in_specs=[
             pl.BlockSpec((block_l, ap), lambda i: (i, 0)),
-            pl.BlockSpec((block_l, 2 * ap), lambda i: (i, 0)),
+            pl.BlockSpec((block_l, ap), lambda i: (i, 0)),
             pl.BlockSpec((block_l, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_l, ap), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((lp, ap), jnp.float32),
+        out_specs=pl.BlockSpec((block_l, ow), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((lp, ow), jnp.float32),
         interpret=interpret,
-    )(base, cc, cap)
+    )(base, cand, cap)
     return out[:l, :a]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "block_l", "interpret", "lane_pad", "pad_to", "shift_chunk"
-    ),
+    static_argnames=("block_l", "interpret", "lane_pad", "shift_chunk"),
 )
 def circle_score_argmin_pallas(
     base: jax.Array,      # (L, A) float32 — zero-padded beyond num_angles[l]
@@ -368,10 +392,9 @@ def circle_score_argmin_pallas(
     valid: jax.Array,     # (L,) int32 admissible shifts per row (≤ num_angles)
     num_angles: jax.Array | None = None,  # (L,) int32 per-row angle counts
     *,
+    interpret: bool,
     block_l: int = DEFAULT_BLOCK_L,
-    interpret: bool = True,
     lane_pad: bool = True,
-    pad_to: int | None = None,
     shift_chunk: int = SHIFT_CHUNK,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused ragged reduction; one launch for any mix of angle counts.
@@ -396,9 +419,9 @@ def circle_score_argmin_pallas(
     """
     l, a = base.shape
     valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32).reshape(-1, 1), (l, 1))
-    base, cc, cap, na, l, a, ap = _prep_inputs(
+    base, cand, cap, na, l, a, ap = _prep_inputs(
         base, cand, capacity, block_l, lane_pad,
-        num_angles=num_angles, pad_to=pad_to,
+        num_angles=num_angles,
     )
     lp = base.shape[0]
     valid = jnp.pad(valid, ((0, lp - l), (0, 0)))
@@ -408,7 +431,7 @@ def circle_score_argmin_pallas(
         grid=(lp // block_l,),
         in_specs=[
             pl.BlockSpec((block_l, ap), lambda i: (i, 0)),
-            pl.BlockSpec((block_l, 2 * ap), lambda i: (i, 0)),
+            pl.BlockSpec((block_l, ap), lambda i: (i, 0)),
             pl.BlockSpec((block_l, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_l, 1), lambda i: (i, 0)),
             pl.BlockSpec((block_l, 1), lambda i: (i, 0)),
@@ -422,5 +445,5 @@ def circle_score_argmin_pallas(
             jax.ShapeDtypeStruct((lp, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(base, cc, cap, valid, na)
+    )(base, cand, cap, valid, na)
     return idx[:l, 0], val[:l, 0]

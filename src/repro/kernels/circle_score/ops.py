@@ -24,7 +24,7 @@ scan replays the host coordinate-search acceptance rule — visit rows in
 order, accept a row's best shift iff it beats the segment's incumbent by
 more than the 1e-12 slack — entirely on device, returning four
 O(num_segments) vectors.  The scan runs in float64 (via
-:func:`jax.experimental.enable_x64`) so the ``excess < best − 1e-12``
+:func:`jax.enable_x64`) so the ``excess < best − 1e-12``
 predicate is evaluated in exactly the arithmetic the host search uses
 (python floats), keeping accepted-shift sequences bit-identical even for
 sub-ulp float32 excess differences.
@@ -35,9 +35,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
-from .kernel import LANE_MULTIPLE, circle_score_argmin_pallas, circle_score_pallas
+from .kernel import (
+    LANE_MULTIPLE,
+    _next_pow2,
+    circle_score_argmin_pallas,
+    circle_score_pallas,
+)
 from .ref import circle_score_argmin_ref, circle_score_ref
 
 __all__ = [
@@ -72,7 +76,24 @@ def bucket_width(w: int) -> int:
         b *= 2
     return b
 
-_ON_TPU = jax.default_backend() == "tpu"
+
+def row_bucket(l: int, block_l: int) -> int:
+    """Bucketed launch height: the smallest power-of-two multiple of
+    ``block_l`` ≥ ``l``.
+
+    The jit key includes the row count, so every distinct chunk height
+    would be one more Mosaic compile — and descent steps and grid chunks
+    change height all the time.  The fused entry points pad rows on the
+    host to this bucket (pad rows are internal: ``valid = 0``, sliced
+    off), capping compiles at O(log max_rows) per width bucket.
+    """
+    return block_l * _next_pow2(-(-l // block_l))
+
+
+def _interpret() -> bool:
+    """Pallas interpret mode everywhere but the TPU, decided at each call:
+    a run on the chip always compiles the kernels with Mosaic."""
+    return jax.default_backend() != "tpu"
 
 # The host rotation search's strict-improvement slack — ONE source of truth,
 # owned by repro.core.compat (numpy-only, no import cycle: compat only loads
@@ -110,63 +131,53 @@ def circle_score(base, cand, capacity, *, tuned=True, block_l=None) -> jax.Array
     cand = jnp.atleast_2d(jnp.asarray(cand, jnp.float32))
     cap = jnp.asarray(capacity, jnp.float32)
     sched = _schedule("circle_score", base.shape[1], tuned, block_l=block_l)
-    return circle_score_pallas(base, cand, cap, interpret=not _ON_TPU, **sched)
+    return circle_score_pallas(base, cand, cap, interpret=_interpret(), **sched)
 
 
-def circle_score_argmin(
-    base, cand, capacity, valid=None,
-    *, tuned=True, block_l=None, shift_chunk=None,
+def _launch_argmin(base, cand, capacity, valid, num_angles, sched):
+    """Pad rows to :func:`row_bucket` on the host and launch the fused
+    kernel.  Returns the bucketed ``(idx, val)`` device arrays (pad rows
+    last) and the real row count."""
+    l = base.shape[0]
+    lb = row_bucket(l, sched["block_l"])
+    cap = np.broadcast_to(np.asarray(capacity, np.float32).reshape(-1), (l,))
+    rows = (0, lb - l)
+    idx, val = circle_score_argmin_pallas(
+        jnp.asarray(np.pad(base, (rows, (0, 0)))),
+        jnp.asarray(np.pad(cand, (rows, (0, 0)))),
+        jnp.asarray(np.pad(cap, rows)),
+        jnp.asarray(np.pad(valid, rows)),
+        # pad rows: period 1, no admissible shift
+        jnp.asarray(np.pad(num_angles, rows, constant_values=1)),
+        interpret=_interpret(), **sched,
+    )
+    return idx, val, l
+
+
+def _argmin_device(
+    base, cand, capacity, valid, variant, *, tuned, block_l, shift_chunk,
 ):
-    """Fused rotation search: ``(best_shift, best_excess)`` per row.
-
-    ``valid`` bounds the admissible shifts per row (Eq. 4: job ``j`` only
-    has ``A / r_j`` distinct rotations); ``None`` admits all ``A`` shifts.
-    Bit-identical to ``np.argmin`` over ``circle_score(...)[l, :valid[l]]``
-    (first-index tie-breaking) without ever materializing the matrix —
-    for every launch schedule, tuned or not.
-    """
-    base = jnp.atleast_2d(jnp.asarray(base, jnp.float32))
-    cand = jnp.atleast_2d(jnp.asarray(cand, jnp.float32))
-    cap = jnp.asarray(capacity, jnp.float32)
+    """Uniform launch: every row spans all ``A`` angles."""
+    base = np.atleast_2d(np.asarray(base, np.float32))
+    cand = np.atleast_2d(np.asarray(cand, np.float32))
     l, a = base.shape
     if valid is None:
-        valid = jnp.full((l,), a, jnp.int32)
+        valid = np.full((l,), a, np.int32)
     else:
-        valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32).reshape(-1), (l,))
+        valid = np.broadcast_to(np.asarray(valid, np.int32).reshape(-1), (l,))
     sched = _schedule(
-        "circle_score_argmin", a, tuned,
-        block_l=block_l, shift_chunk=shift_chunk,
+        variant, a, tuned, block_l=block_l, shift_chunk=shift_chunk
     )
-    return circle_score_argmin_pallas(
-        base, cand, cap, valid, interpret=not _ON_TPU, **sched
+    return _launch_argmin(
+        base, cand, capacity, valid, np.full((l,), a, np.int32), sched
     )
 
 
-def circle_score_ragged_argmin(
-    base, cand, capacity, valid, num_angles, *, pad_to=None,
-    tuned=True, block_l=None, shift_chunk=None, _variant="circle_score_argmin",
+def _ragged_device(
+    base, cand, capacity, valid, num_angles, variant, *, pad_to,
+    tuned, block_l, shift_chunk,
 ):
-    """Ragged fused rotation search: ONE launch over mixed angle counts.
-
-    Args:
-      base, cand: (L, W) float32, row ``l`` real in ``[:num_angles[l]]``
-        and zero-padded above (W = the packed batch width ≥ max A_l).
-      capacity: scalar or (L,) per-row link capacities.
-      valid: (L,) int32 admissible shifts per row (1 ≤ valid ≤ A_l).
-      num_angles: (L,) int32 per-row real angle counts (1 ≤ A_l ≤ W).
-      pad_to: optionally force a wider launch width (tests); the actual
-        launch width is always rounded up to a :func:`bucket_width`
-        bucket — bit-exact by the fold-sum padding invariance — so
-        long-tailed angle-count mixes stop paying one jit recompile per
-        distinct packed width.
-      tuned, block_l, shift_chunk: launch schedule selection (see
-        :func:`_schedule`) — the table lookup is keyed by the bucketed
-        launch width; outputs are bit-identical for every schedule.
-
-    Returns ``(best_shift, best_excess)`` per row, bit-identical to
-    invoking :func:`circle_score_argmin` once per angle-count group on
-    the tightly-sliced rows.
-    """
+    """Ragged launch: validate, bucket the width, launch."""
     base = np.atleast_2d(np.asarray(base, np.float32))
     cand = np.atleast_2d(np.asarray(cand, np.float32))
     l, w = base.shape
@@ -187,17 +198,63 @@ def circle_score_ragged_argmin(
     if wb != w:
         base = np.pad(base, ((0, 0), (0, wb - w)))
         cand = np.pad(cand, ((0, 0), (0, wb - w)))
-    cap = jnp.asarray(capacity, jnp.float32)
     # the table is keyed by exactly this bucketed launch width, so the
     # lookup and the jit cache see the same (variant, bucket) universe
     sched = _schedule(
-        _variant, wb, tuned, block_l=block_l, shift_chunk=shift_chunk
+        variant, wb, tuned, block_l=block_l, shift_chunk=shift_chunk
     )
-    return circle_score_argmin_pallas(
-        jnp.asarray(base), jnp.asarray(cand), cap,
-        jnp.asarray(valid), jnp.asarray(na),
-        interpret=not _ON_TPU, **sched,
+    return _launch_argmin(base, cand, capacity, valid, na, sched)
+
+
+def circle_score_argmin(
+    base, cand, capacity, valid=None,
+    *, tuned=True, block_l=None, shift_chunk=None,
+):
+    """Fused rotation search: ``(best_shift, best_excess)`` per row.
+
+    ``valid`` bounds the admissible shifts per row (Eq. 4: job ``j`` only
+    has ``A / r_j`` distinct rotations); ``None`` admits all ``A`` shifts.
+    Bit-identical to ``np.argmin`` over ``circle_score(...)[l, :valid[l]]``
+    (first-index tie-breaking) without ever materializing the matrix —
+    for every launch schedule, tuned or not.  Returns host arrays.
+    """
+    idx, val, l = _argmin_device(
+        base, cand, capacity, valid, "circle_score_argmin",
+        tuned=tuned, block_l=block_l, shift_chunk=shift_chunk,
     )
+    return np.asarray(idx)[:l], np.asarray(val)[:l]
+
+
+def circle_score_ragged_argmin(
+    base, cand, capacity, valid, num_angles, *, pad_to=None,
+    tuned=True, block_l=None, shift_chunk=None,
+):
+    """Ragged fused rotation search: ONE launch over mixed angle counts.
+
+    Args:
+      base, cand: (L, W) float32, row ``l`` real in ``[:num_angles[l]]``
+        and zero-padded above (W = the packed batch width ≥ max A_l).
+      capacity: scalar or (L,) per-row link capacities.
+      valid: (L,) int32 admissible shifts per row (1 ≤ valid ≤ A_l).
+      num_angles: (L,) int32 per-row real angle counts (1 ≤ A_l ≤ W).
+      pad_to: optionally force a wider launch width (tests); the actual
+        launch width is always rounded up to a :func:`bucket_width`
+        bucket and the row count to a :func:`row_bucket` — both bit-exact
+        by the masking invariants — so long-tailed mixes of angle counts
+        and chunk heights stop paying one jit recompile each.
+      tuned, block_l, shift_chunk: launch schedule selection (see
+        :func:`_schedule`) — the table lookup is keyed by the bucketed
+        launch width; outputs are bit-identical for every schedule.
+
+    Returns host ``(best_shift, best_excess)`` per row, bit-identical to
+    invoking :func:`circle_score_argmin` once per angle-count group on
+    the tightly-sliced rows.
+    """
+    idx, val, l = _ragged_device(
+        base, cand, capacity, valid, num_angles, "circle_score_argmin",
+        pad_to=pad_to, tuned=tuned, block_l=block_l, shift_chunk=shift_chunk,
+    )
+    return np.asarray(idx)[:l], np.asarray(val)[:l]
 
 
 @jax.jit
@@ -233,14 +290,23 @@ def _accept_scan(val, idx, seg_ids, init_best):
     return acc, row, shift, best
 
 
-def _segmin_from(idx, val, seg_ids, init_best):
-    """Shared accept-scan tail of the (ragged) segmin entry points."""
-    seg = jnp.asarray(np.asarray(seg_ids), jnp.int32)
-    with enable_x64():
-        acc, row, shift, best = _accept_scan(
-            val, idx, seg, jnp.asarray(np.asarray(init_best, np.float64))
-        )
-    return acc, row, shift, best
+def _segmin_from(idx, val, l, seg_ids, init_best):
+    """Shared accept-scan tail of the (ragged) segmin entry points.
+
+    ``idx`` / ``val`` stay on device at their bucketed height; their pad
+    rows (past ``l``) go to one extra dummy segment, and the segment axis
+    is padded to a power of two with ``+inf`` incumbents, so the scan's
+    jit key sees bucketed shapes only.  Returns host arrays."""
+    lb = idx.shape[0]
+    num_segs = len(init_best)
+    sp = _next_pow2(num_segs + 1)
+    seg = np.full((lb,), num_segs, np.int32)
+    seg[:l] = np.asarray(seg_ids, np.int32)
+    init = np.full((sp,), np.inf, np.float64)
+    init[:num_segs] = np.asarray(init_best, np.float64)
+    with jax.enable_x64(True):
+        out = _accept_scan(val, idx, jnp.asarray(seg), jnp.asarray(init))
+    return tuple(np.asarray(x)[:num_segs] for x in out)
 
 
 def circle_score_segmin(
@@ -264,15 +330,11 @@ def circle_score_segmin(
     accepted row; entries with ``accepted == False`` carry their init
     state.  Only these four O(S) vectors leave the device.
     """
-    a = np.atleast_2d(np.asarray(base)).shape[1]
-    sched = _schedule(
-        "circle_score_segmin", a, tuned,
-        block_l=block_l, shift_chunk=shift_chunk,
+    idx, val, l = _argmin_device(
+        base, cand, capacity, valid, "circle_score_segmin",
+        tuned=tuned, block_l=block_l, shift_chunk=shift_chunk,
     )
-    idx, val = circle_score_argmin(
-        base, cand, capacity, valid, tuned=False, **sched
-    )
-    return _segmin_from(idx, val, seg_ids, init_best)
+    return _segmin_from(idx, val, l, seg_ids, init_best)
 
 
 def circle_score_ragged_segmin(
@@ -284,9 +346,8 @@ def circle_score_ragged_segmin(
     segmented device-side acceptance scan.  The schedule resolves against
     the ``circle_score_segmin`` table entries, keyed by the bucketed
     launch width."""
-    idx, val = circle_score_ragged_argmin(
-        base, cand, capacity, valid, num_angles, pad_to=pad_to,
-        tuned=tuned, block_l=block_l, shift_chunk=shift_chunk,
-        _variant="circle_score_segmin",
+    idx, val, l = _ragged_device(
+        base, cand, capacity, valid, num_angles, "circle_score_segmin",
+        pad_to=pad_to, tuned=tuned, block_l=block_l, shift_chunk=shift_chunk,
     )
-    return _segmin_from(idx, val, seg_ids, init_best)
+    return _segmin_from(idx, val, l, seg_ids, init_best)

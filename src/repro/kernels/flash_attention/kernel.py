@@ -42,8 +42,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, seq_len: int,
 
     def body(kb, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(kb * block_k, block_k), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(kb * block_k, block_k), slice(None)))
+        k = k_ref[pl.ds(kb * block_k, block_k), :]
+        v = v_ref[pl.ds(kb * block_k, block_k), :]
         s = q @ k.astype(jnp.float32).T                  # (block_q, block_k)
         if causal:
             k_pos = kb * block_k + jax.lax.broadcasted_iota(

@@ -35,6 +35,7 @@ rule).
 from __future__ import annotations
 
 import json
+import logging
 import os
 import warnings
 from dataclasses import dataclass, field
@@ -210,6 +211,13 @@ def get_table() -> TuningTable:
     global _CACHE
     if _CACHE is None:
         _CACHE = load_table(os.environ.get(TABLE_ENV) or None)
+        if not _CACHE.entries:
+            # no table for this backend (the chip has none committed):
+            # every launch takes DEFAULTS — said once per process
+            logging.getLogger(__name__).warning(
+                "no tuning entries for backend %r; the kernels' default "
+                "schedules apply", _CACHE.backend,
+            )
     return _CACHE
 
 

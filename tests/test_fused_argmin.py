@@ -194,15 +194,23 @@ def test_lane_padding_changes_no_output_bit(l, a):
     the real width before each reduction."""
     rng = np.random.default_rng(a)
     base, cand, caps, valid = _random_rows(rng, l, a)
-    on = np.asarray(circle_score_pallas(base, cand, caps, lane_pad=True))
-    off = np.asarray(circle_score_pallas(base, cand, caps, lane_pad=False))
+    on = np.asarray(
+        circle_score_pallas(base, cand, caps, interpret=True, lane_pad=True)
+    )
+    off = np.asarray(
+        circle_score_pallas(base, cand, caps, interpret=True, lane_pad=False)
+    )
     np.testing.assert_array_equal(on, off)
     assert on.shape == (l, a)  # padding never leaks into the result
 
     assert a % LANE_MULTIPLE != 0  # every case exercises a padded width
 
-    i_on, v_on = circle_score_argmin_pallas(base, cand, caps, valid, lane_pad=True)
-    i_off, v_off = circle_score_argmin_pallas(base, cand, caps, valid, lane_pad=False)
+    i_on, v_on = circle_score_argmin_pallas(
+        base, cand, caps, valid, interpret=True, lane_pad=True
+    )
+    i_off, v_off = circle_score_argmin_pallas(
+        base, cand, caps, valid, interpret=True, lane_pad=False
+    )
     np.testing.assert_array_equal(np.asarray(i_on), np.asarray(i_off))
     np.testing.assert_array_equal(np.asarray(v_on), np.asarray(v_off))
 
@@ -672,3 +680,81 @@ def test_ragged_width_bucketing_distinct_buckets_compile_separately():
         )
         _assert_ragged_parity(base, cand, caps, valid, nas)
         assert bucket_width(w) in (256, 1024, 2048)
+
+
+# ---------------------------------------------------------------------- #
+# no silent host fallback: a kernel that fails raises
+# ---------------------------------------------------------------------- #
+KERNEL_ENTRY_POINTS = (
+    "circle_score",
+    "circle_score_argmin",
+    "circle_score_ragged_argmin",
+    "circle_score_segmin",
+    "circle_score_ragged_segmin",
+)
+
+
+def _break_kernels(monkeypatch):
+    from repro.kernels.circle_score import ops
+
+    def failed(*args, **kwargs):
+        raise RuntimeError("circle_score kernel failed")
+
+    for name in KERNEL_ENTRY_POINTS:
+        monkeypatch.setattr(ops, name, failed)
+
+
+@pytest.mark.parametrize("k", [2, 4], ids=["grid", "descent"])
+@pytest.mark.parametrize(
+    "ragged,device_reduce",
+    [(True, True), (False, True), (False, False)],
+    ids=["ragged", "grouped", "full-matrix"],
+)
+def test_kernel_failure_raises_from_find_rotations_batched(
+    monkeypatch, k, ragged, device_reduce
+):
+    """Every kernel path of the batched search propagates the kernel's
+    error instead of returning a numpy-scored result."""
+    rng = np.random.default_rng(70 + k)
+    problems = _mixed_angle_link_problems(rng, wraps=(7, 11), per=1, k=k)
+    _break_kernels(monkeypatch)
+    with pytest.raises(RuntimeError, match="kernel failed"):
+        find_rotations_batched(
+            problems, precision_deg=0.5, ragged=ragged,
+            device_reduce=device_reduce,
+        )
+
+
+def test_kernel_failure_raises_from_cassini_schedule(monkeypatch):
+    """A fine-grid CASSINI epoch scores through the kernels; when they
+    fail, ``schedule`` raises rather than deciding from host scores."""
+    from repro.engine.scenarios import get_scenario
+    from repro.sched import CassiniAugmented, ThemisScheduler
+    from repro.sched.base import ClusterState
+
+    spec = get_scenario("hetero-16rack")
+    topo = spec.topology()
+    state = ClusterState(
+        topology=topo, now_ms=0.0, running=spec.trace(topo)[:10], pending=[]
+    )
+    _break_kernels(monkeypatch)
+    with pytest.raises(RuntimeError, match="kernel failed"):
+        CassiniAugmented(ThemisScheduler(), precision_deg=0.5).schedule(state)
+
+
+@pytest.mark.parametrize(
+    "num_angles,valid,match",
+    [((512, 700), (10, 10), "num_angles"), ((512, 640), (0, 10), "valid")],
+    ids=["angles-above-width", "no-admissible-shift"],
+)
+def test_ragged_input_validation_raises(num_angles, valid, match):
+    """Bad ragged inputs raise ``ValueError`` through the batched search's
+    evaluator — they are rejected, not scored."""
+    from repro.core.compat import _batched_argmin_ragged
+
+    base = np.zeros((2, 640), np.float32)
+    with pytest.raises(ValueError, match=match):
+        _batched_argmin_ragged(
+            base, base, np.full(2, 50.0, np.float32),
+            np.asarray(valid, np.int32), np.asarray(num_angles, np.int32),
+        )

@@ -54,8 +54,10 @@ def _assert_equal(rebuild, delta):
     assert rebuild._allocate() == delta._allocate()
     assert rebuild._mark_rates() == delta._mark_rates()
     if rebuild.vectorized:
-        # a rebuilt incidence row set over the same running order
-        rows = [r.tolist() for r in rebuild._inc.rows]
+        # a rebuilt incidence row set over the same running order (an
+        # engine rebuilt over no running job holds no incidence at all)
+        inc = rebuild._inc
+        rows = [] if inc is None else [r.tolist() for r in inc.rows]
         assert rows == _incidence_sig(delta)
 
 
