@@ -42,10 +42,12 @@ from __future__ import annotations
 
 import math
 import random
+import time
 from dataclasses import dataclass
 
 import numpy as np
 
+from repro import spans
 from repro.cluster.errors import UnknownJobError
 from repro.cluster.job import Job, JobState
 from repro.cluster.shard import MIN_COMPONENTS as _SHARD_MIN_COMPONENTS
@@ -246,6 +248,11 @@ class FluidNetworkSim:
         self._slot_of: dict[str, int] = {}
         self._inc: LinkIncidence | None = None
         self._alloc_cache: dict[bytes, tuple[np.ndarray, np.ndarray]] = {}
+        # while a fluid/advance span is open: host ns spent solving its
+        # alloc-cache misses (None otherwise, so the miss path stays as is)
+        self._solve_ns: int | None = None
+        # event steps of the last advance call
+        self._last_events = 0
         self._rem = np.zeros(0)
         self._dly = np.zeros(0)
         self._mk = np.zeros(0)
@@ -713,10 +720,15 @@ class FluidNetworkSim:
                 # evict only the LRU entry — a cold scan of fresh comm-sets
                 # (256+-rack churn) must not wipe the hot working set
                 del self._alloc_cache[next(iter(self._alloc_cache))]
+            timed = self._solve_ns is not None
+            if timed:
+                t0 = time.perf_counter_ns()
             if self.incremental:
                 rates, marks = self._solve_alloc_incremental(comm_mask)
             else:
                 rates, marks = self._solve_alloc(comm_mask)
+            if timed:
+                self._solve_ns += time.perf_counter_ns() - t0
             hit = (rates, marks, rates > _EPS)
             self._alloc_cache[key] = hit
             self.alloc_solves += 1
@@ -1314,7 +1326,26 @@ class FluidNetworkSim:
         Returns as soon as one or more jobs finish their last iteration (so
         the cluster simulator can react to the departure immediately); the
         finished jobs are returned with ``finish_ms`` / ``state`` set.
+
+        While spans are on (:mod:`repro.spans`), each call records one
+        ``fluid/advance`` span: ``events`` (loop steps), ``solves``
+        (alloc-cache misses) and ``solve_ns`` (host ns solving them).
         """
+        if not spans.enabled():
+            return self._advance(until_ms, max_events)
+        solves0 = self.alloc_solves
+        with spans.span("fluid/advance") as sp:
+            self._solve_ns = 0
+            try:
+                return self._advance(until_ms, max_events)
+            finally:
+                sp.set(events=self._last_events,
+                       solves=self.alloc_solves - solves0,
+                       solve_ns=self._solve_ns)
+                self._solve_ns = None
+
+    def _advance(self, until_ms: float, max_events: int) -> list[Job]:
+        self._last_events = 0
         if not self._execs:
             # empty cluster (every job queued or between arrivals — elastic
             # churn can grow a lone job past the fabric): the fluid state
@@ -1406,6 +1437,7 @@ class FluidNetworkSim:
                 if finished:
                     break
         finally:
+            self._last_events = events
             self._sync_execs()
         return finished
 
@@ -1460,6 +1492,7 @@ class FluidNetworkSim:
                         finished.append(ex.job)
             if finished:
                 break
+        self._last_events = events
         return finished
 
     # -------------------------------------------------------------- #

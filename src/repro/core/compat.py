@@ -36,7 +36,7 @@ from __future__ import annotations
 
 import functools
 import itertools
-from dataclasses import dataclass
+from dataclasses import dataclass, fields
 from typing import Sequence
 
 import numpy as np
@@ -129,6 +129,11 @@ class BatchStats:
     ragged_rows: int = 0        # rows shipped via ragged single launches
     ragged_real_elems: int = 0  # real (unpadded) elements in those launches
     ragged_pad_elems: int = 0   # lane-padded elements those launches shipped
+
+    def add(self, other: "BatchStats") -> None:
+        """Add ``other``'s counts to this one's."""
+        for f in fields(self):
+            setattr(self, f.name, getattr(self, f.name) + getattr(other, f.name))
 
     @property
     def scalar_fallbacks(self) -> int:

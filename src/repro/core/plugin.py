@@ -131,6 +131,9 @@ class CassiniModule:
         # until one runs, or when every link problem was already cached):
         # benches and tests use it to prove no silent scalar fallback.
         self.last_batch_stats: BatchStats | None = None
+        # the same counters summed over every batched solve, under
+        # ``_cache_lock`` (the prefetch thread solves too)
+        self.batch_totals = BatchStats()
 
     # -------------------------------------------------------------- #
     def contended_links(
@@ -382,6 +385,8 @@ class CassiniModule:
                 tuned=self.tuned,
             )
             self.last_batch_stats = stats
+            with self._cache_lock:
+                self.batch_totals.add(stats)
             for key, res in zip(keys, solved):
                 self._cache_put(key, res)
         out: list[Evaluated] = []

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
+
 from .kernel import (
     LANE_MULTIPLE,
     _next_pow2,
@@ -134,43 +136,55 @@ def circle_score(base, cand, capacity, *, tuned=True, block_l=None) -> jax.Array
     return circle_score_pallas(base, cand, cap, interpret=_interpret(), **sched)
 
 
-def _launch_argmin(base, cand, capacity, valid, num_angles, sched):
-    """Pad rows to :func:`row_bucket` on the host and launch the fused
-    kernel.  Returns the bucketed ``(idx, val)`` device arrays (pad rows
-    last) and the real row count."""
+def _pad_rows(base, cand, capacity, valid, num_angles, sched):
+    """Pad rows to :func:`row_bucket` on the host: the five launch
+    operands, pad rows last."""
     l = base.shape[0]
     lb = row_bucket(l, sched["block_l"])
     cap = np.broadcast_to(np.asarray(capacity, np.float32).reshape(-1), (l,))
     rows = (0, lb - l)
-    idx, val = circle_score_argmin_pallas(
-        jnp.asarray(np.pad(base, (rows, (0, 0)))),
-        jnp.asarray(np.pad(cand, (rows, (0, 0)))),
-        jnp.asarray(np.pad(cap, rows)),
-        jnp.asarray(np.pad(valid, rows)),
+    return (
+        np.pad(base, (rows, (0, 0))),
+        np.pad(cand, (rows, (0, 0))),
+        np.pad(cap, rows),
+        np.pad(valid, rows),
         # pad rows: period 1, no admissible shift
-        jnp.asarray(np.pad(num_angles, rows, constant_values=1)),
-        interpret=_interpret(), **sched,
+        np.pad(num_angles, rows, constant_values=1),
     )
-    return idx, val, l
+
+
+def _launch_argmin(operands, sched, interpret):
+    """Upload the padded operands one by one and launch the fused kernel.
+    Returns the bucketed ``(idx, val)`` device arrays."""
+    with spans.span("launch/put", arrays=len(operands)) as sp:
+        if spans.enabled():
+            sp.set(bytes=sum(a.nbytes for a in operands))
+        dev = [jnp.asarray(a) for a in operands]
+    with spans.span("launch/dispatch"):
+        return circle_score_argmin_pallas(*dev, interpret=interpret, **sched)
 
 
 def _argmin_device(
     base, cand, capacity, valid, variant, *, tuned, block_l, shift_chunk,
 ):
     """Uniform launch: every row spans all ``A`` angles."""
-    base = np.atleast_2d(np.asarray(base, np.float32))
-    cand = np.atleast_2d(np.asarray(cand, np.float32))
-    l, a = base.shape
-    if valid is None:
-        valid = np.full((l,), a, np.int32)
-    else:
-        valid = np.broadcast_to(np.asarray(valid, np.int32).reshape(-1), (l,))
-    sched = _schedule(
-        variant, a, tuned, block_l=block_l, shift_chunk=shift_chunk
-    )
-    return _launch_argmin(
-        base, cand, capacity, valid, np.full((l,), a, np.int32), sched
-    )
+    with spans.span("launch/prep"):
+        base = np.atleast_2d(np.asarray(base, np.float32))
+        cand = np.atleast_2d(np.asarray(cand, np.float32))
+        l, a = base.shape
+        if valid is None:
+            valid = np.full((l,), a, np.int32)
+        else:
+            valid = np.broadcast_to(np.asarray(valid, np.int32).reshape(-1), (l,))
+        sched = _schedule(
+            variant, a, tuned, block_l=block_l, shift_chunk=shift_chunk
+        )
+        operands = _pad_rows(
+            base, cand, capacity, valid, np.full((l,), a, np.int32), sched
+        )
+        interpret = _interpret()
+    idx, val = _launch_argmin(operands, sched, interpret)
+    return idx, val, l
 
 
 def _ragged_device(
@@ -178,32 +192,36 @@ def _ragged_device(
     tuned, block_l, shift_chunk,
 ):
     """Ragged launch: validate, bucket the width, launch."""
-    base = np.atleast_2d(np.asarray(base, np.float32))
-    cand = np.atleast_2d(np.asarray(cand, np.float32))
-    l, w = base.shape
-    na = np.broadcast_to(np.asarray(num_angles, np.int32), (l,))
-    valid = np.broadcast_to(np.asarray(valid, np.int32), (l,))
-    if np.any(na < 1) or np.any(na > w):
-        raise ValueError(f"num_angles must lie in [1, {w}], got {na}")
-    if np.any(valid < 1) or np.any(valid > na):
-        # valid == 0 is the *internal* block-padding convention of the
-        # kernel (rows the wrapper slices off); a caller-supplied row with
-        # no admissible shift would come back as a fabricated perfect
-        # (shift 0, excess 0) — reject it instead
-        raise ValueError("valid shift counts must lie in [1, num_angles]")
-    # bucket the packed width host-side (zero-pad the angle axis) so the
-    # jit cache key only ever sees O(log max_width) distinct widths; rows
-    # are masked to num_angles in-kernel, so padding is provably inert
-    wb = bucket_width(max(w, pad_to or 0))
-    if wb != w:
-        base = np.pad(base, ((0, 0), (0, wb - w)))
-        cand = np.pad(cand, ((0, 0), (0, wb - w)))
-    # the table is keyed by exactly this bucketed launch width, so the
-    # lookup and the jit cache see the same (variant, bucket) universe
-    sched = _schedule(
-        variant, wb, tuned, block_l=block_l, shift_chunk=shift_chunk
-    )
-    return _launch_argmin(base, cand, capacity, valid, na, sched)
+    with spans.span("launch/prep"):
+        base = np.atleast_2d(np.asarray(base, np.float32))
+        cand = np.atleast_2d(np.asarray(cand, np.float32))
+        l, w = base.shape
+        na = np.broadcast_to(np.asarray(num_angles, np.int32), (l,))
+        valid = np.broadcast_to(np.asarray(valid, np.int32), (l,))
+        if np.any(na < 1) or np.any(na > w):
+            raise ValueError(f"num_angles must lie in [1, {w}], got {na}")
+        if np.any(valid < 1) or np.any(valid > na):
+            # valid == 0 is the *internal* block-padding convention of the
+            # kernel (rows the wrapper slices off); a caller-supplied row with
+            # no admissible shift would come back as a fabricated perfect
+            # (shift 0, excess 0) — reject it instead
+            raise ValueError("valid shift counts must lie in [1, num_angles]")
+        # bucket the packed width host-side (zero-pad the angle axis) so the
+        # jit cache key only ever sees O(log max_width) distinct widths; rows
+        # are masked to num_angles in-kernel, so padding is provably inert
+        wb = bucket_width(max(w, pad_to or 0))
+        if wb != w:
+            base = np.pad(base, ((0, 0), (0, wb - w)))
+            cand = np.pad(cand, ((0, 0), (0, wb - w)))
+        # the table is keyed by exactly this bucketed launch width, so the
+        # lookup and the jit cache see the same (variant, bucket) universe
+        sched = _schedule(
+            variant, wb, tuned, block_l=block_l, shift_chunk=shift_chunk
+        )
+        operands = _pad_rows(base, cand, capacity, valid, na, sched)
+        interpret = _interpret()
+    idx, val = _launch_argmin(operands, sched, interpret)
+    return idx, val, l
 
 
 def circle_score_argmin(
@@ -222,7 +240,8 @@ def circle_score_argmin(
         base, cand, capacity, valid, "circle_score_argmin",
         tuned=tuned, block_l=block_l, shift_chunk=shift_chunk,
     )
-    return np.asarray(idx)[:l], np.asarray(val)[:l]
+    with spans.span("launch/fetch"):
+        return np.asarray(idx)[:l], np.asarray(val)[:l]
 
 
 def circle_score_ragged_argmin(
@@ -254,7 +273,8 @@ def circle_score_ragged_argmin(
         base, cand, capacity, valid, num_angles, "circle_score_argmin",
         pad_to=pad_to, tuned=tuned, block_l=block_l, shift_chunk=shift_chunk,
     )
-    return np.asarray(idx)[:l], np.asarray(val)[:l]
+    with spans.span("launch/fetch"):
+        return np.asarray(idx)[:l], np.asarray(val)[:l]
 
 
 @jax.jit
@@ -297,16 +317,20 @@ def _segmin_from(idx, val, l, seg_ids, init_best):
     rows (past ``l``) go to one extra dummy segment, and the segment axis
     is padded to a power of two with ``+inf`` incumbents, so the scan's
     jit key sees bucketed shapes only.  Returns host arrays."""
-    lb = idx.shape[0]
-    num_segs = len(init_best)
-    sp = _next_pow2(num_segs + 1)
-    seg = np.full((lb,), num_segs, np.int32)
-    seg[:l] = np.asarray(seg_ids, np.int32)
-    init = np.full((sp,), np.inf, np.float64)
-    init[:num_segs] = np.asarray(init_best, np.float64)
-    with jax.enable_x64(True):
-        out = _accept_scan(val, idx, jnp.asarray(seg), jnp.asarray(init))
-    return tuple(np.asarray(x)[:num_segs] for x in out)
+    with spans.span("accept/dispatch") as handle:
+        lb = idx.shape[0]
+        num_segs = len(init_best)
+        sp = _next_pow2(num_segs + 1)
+        seg = np.full((lb,), num_segs, np.int32)
+        seg[:l] = np.asarray(seg_ids, np.int32)
+        init = np.full((sp,), np.inf, np.float64)
+        init[:num_segs] = np.asarray(init_best, np.float64)
+        if spans.enabled():
+            handle.set(bytes=seg.nbytes + init.nbytes)
+        with jax.enable_x64(True):
+            out = _accept_scan(val, idx, jnp.asarray(seg), jnp.asarray(init))
+    with spans.span("accept/fetch"):
+        return tuple(np.asarray(x)[:num_segs] for x in out)
 
 
 def circle_score_segmin(

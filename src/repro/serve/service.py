@@ -32,8 +32,9 @@ import queue
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 
+from repro import spans
 from repro.cluster.job import Job, JobState
 from repro.cluster.network import FluidNetworkSim
 from repro.cluster.simulator import Metrics
@@ -271,6 +272,9 @@ class SchedulerService:
             if module is not None:
                 out["link_cache_hits"] = float(module.cache_hits)
                 out["link_cache_misses"] = float(module.cache_misses)
+                totals = module.batch_totals
+                for f in fields(totals):
+                    out[f"batch_{f.name}"] = float(getattr(totals, f.name))
         except Exception:  # pragma: no cover - defensive
             pass
         out["decisions"] = float(len(self.decisions))
@@ -453,38 +457,42 @@ class SchedulerService:
 
     # ---------------------- scheduling ---------------------------- #
     def _reschedule(self, now: float, trigger: str) -> None:
-        self._join_prefetch()  # the pipeline/module is single-consumer
-        state = ClusterState(
-            topology=self.topo, now_ms=now, running=list(self._running),
-            pending=[],
-        )
-        t0 = time.perf_counter()
-        decision = self._decide(state)
-        self.metrics.observe("schedule", (time.perf_counter() - t0) * 1e3)
-        self.metrics.count(f"reschedule_{trigger}")
-        self.decisions.append((now, decision))
-        placed: list[Job] = []
-        for job in self._running:
-            servers = decision.placements.get(job.job_id, ())
-            if servers:
-                job.placement = tuple(servers)
-                job.state = JobState.RUNNING
-                directive = (
-                    decision.plan.directive_for(job.job_id)
-                    if decision.plan is not None
-                    else None
-                )
-                if directive is not None:
-                    job.apply_directive(directive)
+        # the root span of one decision: every span under it, the
+        # prefetch it launches included, carries this decision's index
+        with spans.span("serve/reschedule", decision=len(self.decisions),
+                        trigger=trigger):
+            self._join_prefetch()  # the pipeline/module is single-consumer
+            state = ClusterState(
+                topology=self.topo, now_ms=now, running=list(self._running),
+                pending=[],
+            )
+            t0 = time.perf_counter()
+            decision = self._decide(state)
+            self.metrics.observe("schedule", (time.perf_counter() - t0) * 1e3)
+            self.metrics.count(f"reschedule_{trigger}")
+            self.decisions.append((now, decision))
+            placed: list[Job] = []
+            for job in self._running:
+                servers = decision.placements.get(job.job_id, ())
+                if servers:
+                    job.placement = tuple(servers)
+                    job.state = JobState.RUNNING
+                    directive = (
+                        decision.plan.directive_for(job.job_id)
+                        if decision.plan is not None
+                        else None
+                    )
+                    if directive is not None:
+                        job.apply_directive(directive)
+                    else:
+                        job.clear_directive()
+                    placed.append(job)
                 else:
-                    job.clear_directive()
-                placed.append(job)
-            else:
-                job.placement = ()
-                job.state = JobState.PENDING  # queued: no GPUs this epoch
-        mode = self.net.configure_incremental(placed)
-        self.metrics.count(f"configure_{mode}")
-        self._maybe_prefetch()
+                    job.placement = ()
+                    job.state = JobState.PENDING  # queued: no GPUs this epoch
+            mode = self.net.configure_incremental(placed)
+            self.metrics.count(f"configure_{mode}")
+            self._maybe_prefetch()
 
     def _decide(self, state: ClusterState) -> Decision:
         """One scheduling decision, degrading gracefully when allowed.
@@ -558,16 +566,18 @@ class SchedulerService:
         pipeline = self._pipeline
         pred_now = self._next_epoch
         pred_running = list(self._running)
+        launcher = spans.current()
 
         def warm():
-            st = ClusterState(
-                topology=self.topo, now_ms=pred_now, running=pred_running,
-                pending=[],
-            )
-            out = None
-            for stage in pipeline.stages[:-1]:  # Allocate, Propose, Score
-                out = stage.run(st, out)
-            return out
+            with spans.span("prefetch/warm", parent=launcher):
+                st = ClusterState(
+                    topology=self.topo, now_ms=pred_now, running=pred_running,
+                    pending=[],
+                )
+                out = None
+                for stage in pipeline.stages[:-1]:  # Allocate, Propose, Score
+                    out = stage.run(st, out)
+                return out
 
         self._prefetch_future = self._prefetch_pool.submit(warm)
         self.metrics.count("prefetch_launched")

@@ -91,6 +91,44 @@ class TestGoldenEquivalence:
         assert tel_on["prefetch_launched"] > 0
         assert "prefetch_launched" not in tel_off
 
+    def test_batch_totals_sum_every_solve(self, monkeypatch):
+        """``batch_*`` telemetry sums ``BatchStats`` over every batched
+        solve, the prefetch thread's included; ``last_batch_stats`` stays
+        the last call's."""
+        import dataclasses
+
+        import repro.core.plugin as plugin
+        from repro.core import compat
+
+        # the kernel path even at the scenario's coarse circles
+        monkeypatch.setattr(compat, "_kernel_eligible", lambda backend, a: True)
+        calls, lock = [], threading.Lock()
+        solve = plugin.find_rotations_batched
+
+        def recorded(batch, **kw):
+            out = solve(batch, **kw)
+            with lock:
+                calls.append(kw["stats"])
+            return out
+
+        monkeypatch.setattr(plugin, "find_rotations_batched", recorded)
+        spec = get_scenario("dynamic-burst")
+        svc = SchedulerService(
+            spec.topology(), spec.make_scheduler("th+cassini"),
+            epoch_ms=spec.epoch_ms, seed=spec.sim_seed, prefetch=True,
+        )
+        with svc:
+            for job in spec.arrival_stream(svc.topo):
+                svc.submit(JobArrival(job))
+            svc.drain(spec.horizon_ms)
+            tel = svc.telemetry()
+        assert len(calls) > 1
+        module = svc.scheduler.module
+        assert module.last_batch_stats in calls or module.last_batch_stats is None
+        for f in dataclasses.fields(compat.BatchStats):
+            assert tel[f"batch_{f.name}"] == sum(getattr(c, f.name) for c in calls)
+        assert tel["batch_problems"] > 0 and tel["batch_launches"] > 0
+
 
 # --------------------------------------------------------------------- #
 # service semantics
