@@ -2,8 +2,14 @@
 
 The cell, its configuration and its traffic mix are found by name: the
 cell in ``BENCHMARK.json``, the configuration in the file the cell's
-``configs`` entry names, the mix in ``mixes/<traffic>.json``, and each
-per-layer metric's reader in ``metrics/<metric>.py``.
+``configs`` entry names, the mix in ``mixes/<traffic>.json``, each
+per-layer metric's reader in ``metrics/<metric>.py``, and the plain
+reference in ``reference.py``, its loop rule replaced by the one of
+``references/<configuration>.py`` where the configuration has that
+file.  The configuration's ``host.kind`` names the host scheduler
+(``fixed``: pinned placements; ``themis``: Themis with
+``num_candidates`` candidates), and the mix's ``process`` the arrival
+process (``stream.py``).
 
 The loop is closed: a cluster's host scheduler calls CASSINI once per
 trigger and waits, so one client replays the cell's seeded event stream
@@ -34,7 +40,7 @@ import sys
 import time
 from pathlib import Path
 
-from . import check, devtrace, probes, stats, stream
+from . import check, devtrace, probes, reference, stats, stream
 
 CHIP = Path(__file__).resolve().parent
 CHIP_REL = Path("benchmarks") / "chip"
@@ -67,12 +73,26 @@ def load_cell(root: Path, name: str) -> dict:
 
 def metric_reader(root: Path, name: str):
     path = root / CHIP_REL / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"chipbench_metric_{name}", path)
-    if spec is None or not path.exists():
+    if not path.exists():
         raise FileNotFoundError(f"no reader for metric {name!r} at {path}")
+    return _module(path, f"chipbench_metric_{name}").read
+
+
+def reference_for(root: Path, config: str):
+    """The module whose loop rule, ``aligned_links``, the check takes: the
+    configuration's own ``references/<config>.py`` where it exists, else
+    ``reference``.  Nothing else of that module is used."""
+    path = root / CHIP_REL / "references" / f"{config}.py"
+    if not path.exists():
+        return reference
+    return _module(path, f"chipbench_reference_{config}")
+
+
+def _module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
 
 
 # ---------------------------------------------------------------------- #
@@ -80,7 +100,7 @@ def metric_reader(root: Path, name: str):
 # ---------------------------------------------------------------------- #
 def build_service(cfg: dict, rec: probes.Recorder):
     from repro.cluster.topology import Topology
-    from repro.sched import CassiniAugmented
+    from repro.sched import CassiniAugmented, ThemisScheduler
     from repro.sched.fixed import FixedPlacementScheduler
     from repro.serve import SchedulerService
 
@@ -93,9 +113,12 @@ def build_service(cfg: dict, rec: probes.Recorder):
         oversubscription=float(t["oversubscription"]),
     )
     h = cfg["host"]
-    if h["kind"] != "fixed":
+    if h["kind"] == "fixed":
+        host = FixedPlacementScheduler({})
+    elif h["kind"] == "themis":
+        host = ThemisScheduler(num_candidates=h["num_candidates"], seed=h["seed"])
+    else:
         raise ValueError(f"unknown host scheduler {h['kind']!r}")
-    host = FixedPlacementScheduler({})
     c = cfg["cassini"]
     sched = CassiniAugmented(
         host, num_candidates=h["num_candidates"],
@@ -208,11 +231,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         undo.append(probes.wrap_kernels(rec))
         probes.wrap_handler(svc, rec)
     chk = mix["check"]
-    spr = cfg["topology"]["servers_per_rack"]
-    hubs = sorted({slot[0] // spr for slot in stream.slot_layout(cfg)})
-    watched = set(random.Random(seed ^ 0xF1D).sample(
-        hubs, min(chk["fluid_groups"], len(hubs))))
-    probes.wrap_fluid(svc, rec, watched, spr)
+    racks = _fluid_racks(cfg)
+    fluid_racks = set(random.Random(seed ^ 0xF1D).sample(
+        racks, min(chk["fluid_groups"], len(racks))))
+    probes.wrap_fluid(svc, rec)
     specs: dict = {}
     client = Client(svc, host, stream.events(cfg, mix, seed), specs)
     t_build = time.perf_counter()
@@ -341,17 +363,22 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
 
     # ------------------------------ correctness ------------------------- #
     t_ref = time.perf_counter()
-    judge = check.Judge(cfg, specs)
+    ref = reference_for(cell["root"], cfg["name"])
+    judge = check.Judge(cfg, specs, rule=ref.aligned_links)
     outputs = dict(proxy.outputs)
-    got = check.readings(judge, rec.scored, outputs, rec.fluid, chk, seed)
+    got = check.readings(judge, rec.scored, outputs, rec.fluid, chk, seed,
+                         fluid_racks)
     limits = cfg["limits"]
     degraded = int(tel.get("degraded_decisions", 0))
     nums = got["numbers"]
     correct = check.verdict(got, degraded, limits)
     log(f"reference_s={time.perf_counter() - t_ref!r} "
         f"decisions_checked={got['decisions']} links_checked={got['links']} "
-        f"fluid_hubs={sorted(watched)} fluid_states={got['fluid_states']} "
-        f"fluid_chains={got['fluid_chains']} fluid_near={got['fluid_near']} "
+        f"reference={Path(ref.__file__).name} fluid_racks={sorted(fluid_racks)} "
+        f"fluid_states={got['fluid_states']} fluid_chains={got['fluid_chains']} "
+        f"fluid_near={got['fluid_near']} fluid_cut={got['fluid_cut']} "
+        f"fluid_jobs={got['fluid_jobs']} "
+        f"candidates={got['candidates']} discarded={got['discarded']} "
         f"faults={got['faults']}")
     log(f"worst_link {judge.worst!r}")
     checks = {k: {"value": nums[k], "limit": limits[k]} for k in check.NUMBERS}
@@ -363,7 +390,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
     result["checks"] = checks
     if control:
         ctl = check.readings(judge, rec.scored, outputs, rec.fluid, chk,
-                             seed, control=True)
+                             seed, fluid_racks, control=True)
         result["control"] = dict(ctl, correct=check.verdict(ctl, degraded, limits))
     return result
 
@@ -383,9 +410,21 @@ def _occupancy(svc) -> str:
     return f"{len(running)}jobs/{gpus}gpus"
 
 
+def _fluid_racks(cfg: dict) -> list[int]:
+    """The racks the fluid check seeds its watched set from: the hub racks
+    of a ``hub_leaf`` layout, else every rack."""
+    if cfg["layout"]["kind"] == "hub_leaf":
+        spr = cfg["topology"]["servers_per_rack"]
+        return sorted({slot[0] // spr for slot in stream.slot_layout(cfg)})
+    return list(range(cfg["topology"]["racks"]))
+
+
 def _thread_s(spans, thread: str) -> float:
+    """Seconds of the host stages' spans on the threads whose name starts
+    with ``thread`` (a pool numbers its threads: ``serve-prefetch_0``)."""
     return sum(s.t1 - s.t0 for s in spans
-               if s.thread == thread and s.name in ("allocate", "propose", "score"))
+               if s.thread.startswith(thread)
+               and s.name in ("allocate", "propose", "score"))
 
 
 def _halves(decisions, t0, t1, mid, cache0, cache1) -> dict:

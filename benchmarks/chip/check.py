@@ -16,19 +16,48 @@ Three numbers, each compared with a limit the configuration states:
     the pinned layout (fixed host) or none of the candidates, or whose
     per-job time-shifts do not realize, on some contended link of the
     chosen placement, the link-level shifts found there (Theorem 1).
+    Which candidates are discarded and which contended links alignment
+    covers is the reference's loop rule, ``aligned_links``.
 ``fluid_gap``
-    The tenants of a few seeded hub racks are replayed by the reference
-    fluid model over the window in back-to-back chains of a fixed span of
-    cluster time.  A chain starts from the program's state and applies
-    every decision itself (time-shift deltas, pacing armed or not, and
-    its period, which must be the reference circle's).  Over every later
-    state the program reached: the largest gap in job progress
-    (iterations, continuous across pieces) and in pending delay (in
-    iterations); 1 when a job runs on one side only.  Chains are short
-    because the fluid model is chaotic: a rounding difference grows
-    e-fold every few seconds of cluster time.  A chain in which the
-    pacing agent met one of its steps within rounding (``NEAR_MS``) ends
-    at the next state uncompared: there either branch is sound.
+    A watched set of jobs is replayed by the reference fluid model over
+    the window in back-to-back chains of a fixed span of cluster time.
+    At a chain's first state the watched set is the jobs with a server on
+    a few seeded racks, closed under link sharing: every job that shares
+    a link with a watched job is watched too, until nothing is added (on
+    a ``hub_leaf`` layout, the tenants of the seeded hubs).  A chain
+    starts from the program's state and applies every decision itself
+    (time-shift deltas, pacing armed or not, and its period), with each
+    job's placement, and its worker count, as the decision the service
+    acted on gave them.  A job new to the cluster that a decision places
+    on a seeded rack or on a watched job's links joins the set; a watched
+    job that a decision moves or resizes restarts its iteration there
+    after the configuration's migration pause (``reference.move``).  A
+    decision that links to the set a job that ran before ends the chain
+    at the next state, uncompared: the reference has no state of it.
+    Over every later state the program reached: the largest gap in job
+    progress (iterations, continuous across pieces) and in pending delay
+    (in iterations), a job that has finished standing at its last
+    iteration with no delay.  Chains are short because the fluid model
+    is chaotic: a rounding difference grows e-fold every few seconds of
+    cluster time.  A chain in which the pacing agent met one of its steps
+    within rounding (``NEAR_MS``) ends at the next state uncompared: there
+    either branch is sound.  Every paced period a decision delivers is
+    held, on each contended link alignment covers in the chosen placement,
+    to the reference circle's quantized period of the job there, the
+    largest over its links (the program's rule).
+
+Everything the check compares with is ``reference.py``'s, which imports
+nothing of the program.  The one exception is the loop rule: the
+``aligned_links`` of ``references/<configuration>.py`` where the
+configuration has that file (``Judge``'s ``rule``; nothing else of the
+file is used), else ``reference.aligned_links`` (Algorithm 2).
+
+The check runs after the window closes and is not timed.  It may take no
+longer than the window: with set-up and the window it must end within a
+run's 360 s.  Its seconds are printed as ``reference_s`` on an earlier
+line.  Most of them go to the fluid replay, whose cost grows with the
+watched set and with the cluster time the window covers (about 0.6 s a
+cluster second with some 50 jobs watched, on a TPU v5 lite host).
 
 The control puts the reference in the program's place with one stated
 guarantee broken: rotations searched on a unified circle of half the
@@ -56,11 +85,30 @@ def semantics(cfg: dict) -> dict:
                                    ("precision_deg", "quantum_ms", "seed")}}
 
 
+def closure(seeds, placements: dict, fabric) -> set:
+    """``seeds`` and every job of ``placements`` (job -> servers) that
+    shares a link with one of them, until nothing is added."""
+    users: dict[str, list] = {}
+    for jid, servers in placements.items():
+        for name in fabric.links(servers):
+            users.setdefault(name, []).append(jid)
+    out = set(seeds)
+    todo = list(out)
+    while todo:
+        for name in fabric.links(placements[todo.pop()]):
+            for other in users[name]:
+                if other not in out:
+                    out.add(other)
+                    todo.append(other)
+    return out
+
+
 class Judge:
-    def __init__(self, cfg: dict, specs: dict) -> None:
+    def __init__(self, cfg: dict, specs: dict, rule=ref.aligned_links) -> None:
         self.cfg = cfg
         self.sem = semantics(cfg)
         self.specs = specs
+        self.rule = rule            # the loop rule (``reference.aligned_links``)
         self.fabric = ref.Fabric(cfg["topology"])
         self._circles: dict = {}
         self._opt: dict = {}
@@ -113,8 +161,8 @@ class Judge:
         faults = []
         cand_scores, cand_links = [], []
         for i, pl in enumerate(cands):
-            links = ref.contended(self.fabric, pl)
-            if ref.has_loop({js: c for js, (c, _) in links.items()}):
+            links = self.rule(ref.contended(self.fabric, pl))
+            if links is None:
                 cand_scores.append(-math.inf)
                 cand_links.append({})
                 continue
@@ -183,8 +231,8 @@ class Judge:
         return {"align_gap": align, "faults": faults}
 
     def _ref_score(self, pl) -> float:
-        links = ref.contended(self.fabric, pl)
-        if ref.has_loop({js: c for js, (c, _) in links.items()}):
+        links = self.rule(ref.contended(self.fabric, pl))
+        if links is None:
             return -math.inf
         s = [ref.link_score(self.circle(self._jobs(js, pl)),
                             self.optimum(self._jobs(js, pl), cap), cap)
@@ -192,27 +240,52 @@ class Judge:
         return float(np.mean(s)) if s else 1.0
 
     # -------------------------------------------------------------- #
-    def fluid(self, log: list, horizon_ms: float,
+    def fluid(self, log: list, horizon_ms: float, racks: set,
               control: bool = False) -> tuple[float, list]:
-        """Replay the watched tenants' fluid log in chains of
+        """Replay a watched set of jobs through the fluid log in chains of
         ``horizon_ms`` of cluster time: each chain starts from the
-        program's state at its first snapshot and carries its own state
-        through every later decision and advance, where it is compared.
-        Every paced period a decision delivers is held to the reference
-        circle's."""
+        program's state at its first snapshot, watching the jobs with a
+        server on ``racks`` and every job linked to them, and carries its
+        own state through every later decision and advance, where it is
+        compared.  Every paced period a decision delivers is held to the
+        reference circle's."""
         f = np.float32 if control else float
-        specs = {k: (s.model, s.workers, s.batch, s.placement, s.iters)
-                 for k, s in self.specs.items()}
+        spr = self.cfg["topology"]["servers_per_rack"]
+        pause = self.cfg["service"]["migration_pause_ms"]
+
+        def on_racks(placements: dict) -> set:
+            return {j for j, servers in placements.items()
+                    if any(s // spr in racks for s in servers)}
+
         sim, t0 = None, -math.inf
+        watched: dict = {}          # job -> placement, in the current chain
         gap, faults = 0.0, []
-        self.fluid_chains = self.fluid_near = 0
-        for kind, t, data in log:
+        self.fluid_chains = self.fluid_near = self.fluid_cut = self.fluid_jobs = 0
+        for kind, t, data, placements, *fresh in log:
             if kind == "configure":
                 if not control:
-                    faults += self._paced(data)
-                if sim is not None:
-                    sim.advance(t)
-                    sim.configure(data, specs)
+                    faults += self._paced(data, placements)
+                if sim is None:
+                    continue
+                live = {j for j in watched if j in placements}
+                fresh = fresh[0]
+                grown = closure(live | (on_racks(placements) & fresh),
+                                placements, self.fabric)
+                if not grown - live <= fresh:
+                    # a job that ran before joins the set: the reference
+                    # has no state of it to carry on
+                    self.fluid_cut += 1
+                    sim = None
+                    continue
+                moved = [j for j in live if placements[j] != watched[j]]
+                watched = {j: placements[j] for j in grown}
+                self.fluid_jobs = max(self.fluid_jobs, len(watched))
+                specs = self._fluid_specs(watched)
+                sim.advance(t)
+                for j in moved:
+                    if j in sim.jobs:
+                        ref.move(sim, j, specs[j], data[j][0], pause)
+                sim.configure({j: data[j] for j in grown}, specs)
                 continue
             if sim is not None:
                 sim.advance(t)
@@ -221,41 +294,70 @@ class Judge:
                 # rounding is not compared, since either branch is sound
                 self.fluid_chains += 1
                 self.fluid_near += bool(sim is not None and sim.near)
+                watched = {j: placements[j] for j in
+                           closure(on_racks(placements), placements, self.fabric)}
+                self.fluid_jobs = max(self.fluid_jobs, len(watched))
                 sim, t0 = ref.FluidRef(self.cfg, self.fabric, f=f), t
-                sim.load(t, data, specs)
+                sim.load(t, {j: data[j] for j in watched},
+                         self._fluid_specs(watched))
                 continue
-            for jid in set(data) | set(sim.jobs):
+            for jid in watched:
                 j, got = sim.jobs.get(jid), data.get(jid)
+                if j is None and got is None:
+                    continue            # finished on both sides
                 if j is None or got is None:
-                    return 1.0, faults + [f"fluid: {jid} runs on one side only"]
-                if not 0 <= got[1] < len(j["segs"]):
+                    # a job one side has finished stands at its last
+                    # iteration there, with no delay: a completion within
+                    # rounding of this state reads as a gap within rounding
+                    end = self._finished(jid, watched[jid])
+                    j = j or end
+                if got is not None and not 0 <= got[1] < len(j["segs"]):
                     return 1.0, faults + [f"fluid: {jid} in no piece"]
-                prog = dict(j, iters_done=got[0], seg=got[1], remaining=got[2])
+                prog = end if got is None else dict(
+                    j, iters_done=got[0], seg=got[1], remaining=got[2],
+                    delay=got[3])
                 gap = max(gap,
                           abs(ref.position(prog, j["segs"])
                               - ref.position(j, j["segs"])),
-                          abs(float(got[3]) - float(j["delay"])) / j["solo"])
+                          abs(float(prog["delay"]) - float(j["delay"])) / j["solo"])
         return gap, faults
 
-    def _paced(self, directives: dict) -> list:
+    def _finished(self, jid: str, placement) -> dict:
+        """Job ``jid`` at its end, as ``FluidRef`` keeps a job: all its
+        iterations done, nothing of the next begun, no delay."""
+        model, workers, batch, _, iters = self._fluid_specs({jid: placement})[jid]
+        it, ph = ref.pattern(self.cfg["models"][model], workers, batch)
+        segs = ref.segments(it, ph)
+        links = self.fabric.links(placement)
+        kind, dur, gbps = segs[0]
+        work = dur if kind == "compute" or not links else gbps * dur * 1e-3
+        return {"segs": segs, "solo": it, "links": links, "iters_done": iters,
+                "seg": 0, "remaining": work, "delay": 0.0}
+
+    def _fluid_specs(self, placements: dict) -> dict:
+        """job -> ``(model, workers, batch, placement, iters)`` as
+        ``FluidRef`` takes them, the workers those the placement holds."""
+        return {j: (self.specs[j].model, len(pl), self.specs[j].batch, pl,
+                    self.specs[j].iters) for j, pl in placements.items()}
+
+    def _paced(self, directives: dict, placements: dict) -> list:
         """Each paced period is the job's quantized period on the unified
-        circle of its hub link (perimeter over its wraps)."""
-        hubs: dict = {}
-        for jid in directives:
-            hubs.setdefault(self.specs[jid].placement[0], []).append(jid)
+        circle of a contended link that alignment covers in the placement
+        the decision chose (perimeter over its wraps), the largest over
+        its links."""
+        links = self.rule(ref.contended(self.fabric, placements))
+        want: dict = {}
+        for js in links or ():
+            c = self.circle(self._jobs(js, placements))
+            for i, jid in enumerate(js):
+                want[jid] = max(want.get(jid, 0.0),
+                                c["perimeter_ms"] / c["wraps"][i])
         faults = []
-        for jids in hubs.values():
-            jids = sorted(jids)
-            if len(jids) < 2:
-                continue
-            pl = {j: self.specs[j].placement for j in jids}
-            c = self.circle(self._jobs(tuple(jids), pl))
-            for i, jid in enumerate(jids):
-                paced = directives[jid][2]
-                want = c["perimeter_ms"] / c["wraps"][i]
-                if paced is not None and abs(paced - want) > 1e-9 * want:
-                    faults.append(f"paced period of {jid}: {paced!r}, "
-                                  f"circle's {want!r}")
+        for jid, w in want.items():
+            paced = directives[jid][2]
+            if paced is not None and abs(paced - w) > 1e-9 * w:
+                faults.append(f"paced period of {jid}: {paced!r}, "
+                              f"circle's {w!r}")
         return faults
 
 
@@ -277,7 +379,7 @@ def sample_decisions(scored: list, n: int, seed: int) -> list:
 
 
 def readings(judge: Judge, scored: list, outputs: dict, fluid: list,
-             chk: dict, seed: int, control: bool = False) -> dict:
+             chk: dict, seed: int, racks: set, control: bool = False) -> dict:
     """The three numbers over the seeded sample, and what was compared."""
     faults: list[str] = []
     align = []
@@ -292,8 +394,9 @@ def readings(judge: Judge, scored: list, outputs: dict, fluid: list,
         align.append(r["align_gap"])
         faults += r["faults"]
     short = list(judge.shortfall.values())
-    fluid_gap, fluid_faults = judge.fluid(fluid, chk["fluid_chain_ms"],
-                                          control=control)
+    fluid_gap, fluid_faults = judge.fluid(
+        fluid, chk["fluid_chain_ms"], racks, control=control)
+    cands = [pl for _, _, sp in scored for pl in sp.placements]
     out = {
         "rotation_gap": float(np.mean(short)) if short else 0.0,
         "align_gap": float(np.mean(align)) if align else 0.0,
@@ -303,7 +406,11 @@ def readings(judge: Judge, scored: list, outputs: dict, fluid: list,
     return {"numbers": out, "faults": faults[:5], "decisions": len(picked),
             "links": len(short),
             "fluid_states": sum(1 for e in fluid if e[0] == "snap"),
-            "fluid_chains": judge.fluid_chains, "fluid_near": judge.fluid_near}
+            "fluid_chains": judge.fluid_chains, "fluid_near": judge.fluid_near,
+            "fluid_cut": judge.fluid_cut, "fluid_jobs": judge.fluid_jobs,
+            "candidates": len(cands),
+            "discarded": sum(judge.rule(
+                ref.contended(judge.fabric, pl)) is None for pl in cands)}
 
 
 def verdict(got: dict, degraded: int, limits: dict) -> bool:

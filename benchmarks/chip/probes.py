@@ -10,7 +10,7 @@ kernel entry points) and record
   service makes (always on: it is the end-to-end metric);
 * the inputs and outputs the correctness check compares (always on:
   references only, no copies on the timed path except the fluid state
-  of a few watched tenants);
+  of the running jobs after each advance);
 * with tracing on, host spans per layer (also emitted as
   ``jax.profiler.TraceAnnotation`` so they share the device trace's
   clock), ``BatchStats`` summed over calls, and per-launch kernel shapes
@@ -183,41 +183,47 @@ def wrap_kernels(rec: Recorder):
     return undo
 
 
-def wrap_fluid(svc, rec: Recorder, hubs: set, spr: int) -> None:
-    """Spans on the fluid engine, and the log the reference replays for
-    the tenants of the hub racks ``hubs`` (a tenant's first server is on
-    its hub), in the window: what each decision being applied asks of
-    each of them (time-shift, pacing and its period, or nothing), and
-    their state after every ``advance``."""
+def wrap_fluid(svc, rec: Recorder) -> None:
+    """Spans on the fluid engine, and the log the reference replays, in
+    the window: for each decision being applied, what it asks of each job
+    it places (time-shift, pacing and its period, or nothing), where it
+    places it, and which of them are new to the fluid engine with no
+    iteration done; after every ``advance``, each running job's state and
+    placement.  The check picks the jobs it watches from this log.
+
+    This runs inside the timed window, on every job and not only on those
+    the check will watch, since which ones it watches on an open layout
+    is known only from later states: about 30 us a snapshot of 51 jobs
+    (CPU), against hundreds of milliseconds a decision."""
     net = svc.net
     advance = net.advance
     configure = net.configure_incremental
-    watched: list = []
 
     def probe_advance(until_ms, **kw):
         with rec.span("fluid.advance"):
             finished = advance(until_ms, **kw)
-        if rec.in_window and watched:
+        if rec.in_window:
             execs = net._execs
             rec.fluid.append(("snap", net.now_ms, {
                 jid: (ex.job.iters_done, ex.seg_idx, ex.remaining, ex.delay_ms,
                       ex.ideal_next_ms, ex.consec_adjust, ex.applied_shift_ms,
                       ex.iter_start_ms, ex.paced_iter_ms)
-                for jid in watched if (ex := execs.get(jid)) is not None}))
+                for jid, ex in execs.items()},
+                {jid: ex.job.placement for jid, ex in execs.items()}))
         return finished
 
     def probe_configure(jobs):
-        mine = [j.job_id for j in jobs
-                if j.placement and j.placement[0] // spr in hubs]
-        watched[:] = mine
         if rec.in_window:
             plan = svc.decisions[-1][1].plan
             shifts = plan.time_shifts_ms if plan is not None else {}
             rec.fluid.append(("configure", net.now_ms, {
-                jid: (None, False, None) if jid not in shifts else
-                (float(shifts[jid]), plan.align_ok(jid),
-                 plan.paced_periods_ms.get(jid))
-                for jid in mine}))
+                j.job_id: (None, False, None) if j.job_id not in shifts else
+                (float(shifts[j.job_id]), plan.align_ok(j.job_id),
+                 plan.paced_periods_ms.get(j.job_id))
+                for j in jobs},
+                {j.job_id: j.placement for j in jobs},
+                frozenset(j.job_id for j in jobs
+                          if j.job_id not in net._execs and j.iters_done == 0)))
         with rec.span("fluid.configure"):
             return configure(jobs)
 

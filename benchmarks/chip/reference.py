@@ -258,6 +258,17 @@ def has_loop(links: dict[tuple, float]) -> bool:
     return False
 
 
+def aligned_links(links: dict[tuple, tuple]) -> dict[tuple, tuple] | None:
+    """The loop rule: which contended links of a candidate (``contended``'s
+    output) alignment covers, or None when the candidate is discarded.
+    Algorithm 2 (§4.2): a candidate whose job-link affinity graph has a
+    loop is discarded; otherwise alignment covers every contended link.
+
+    This is the one function a configuration's own reference
+    (``references/<configuration>.py``) may replace."""
+    return None if has_loop(links) else links
+
+
 def congruent(t: dict, w: dict, iters: dict, tol: float = 1e-6) -> bool:
     """Theorem 1 on one link: some δ has ``t_j - w_j ≡ δ (mod iter_j)`` for
     every job, ``t`` the decision's time-shifts and ``w`` the link-level
@@ -520,6 +531,33 @@ class FluidRef:
                     if j["iters_done"] >= j["iters"]:
                         del jobs[k]
         self.now = f(max(self.now, until_ms))
+
+
+def move(sim: FluidRef, jid: str, spec: tuple, shift: float | None,
+         pause_ms: float) -> bool:
+    """A decision moves or resizes the running job ``jid`` of ``sim`` onto
+    ``spec`` (``(model, workers, batch, placement, iters)``): a
+    checkpoint-restore.  Its finished iterations are kept, the iteration in
+    progress is lost, and it starts a new one on the new placement after
+    ``pause_ms`` plus its time-shift target (``shift``, or the shift it has
+    applied when the decision gives it none); pacing is re-armed by the
+    decision (``FluidRef.configure``).  A job whose links and pattern stay
+    as they were is not moved.  Returns whether it was."""
+    f = sim.f
+    old = sim.jobs[jid]
+    model, workers, batch, placement, _ = spec
+    it, ph = pattern(sim.cfg["models"][model], workers, batch)
+    segs = segments(it, ph)
+    links = sim.fabric.links(placement)
+    if links == old["links"] and segs == old["segs"]:
+        return False
+    target = old["applied"] if shift is None else shift
+    j = sim.jobs[jid] = dict(
+        old, segs=segs, solo=it, links=links, seg=0,
+        delay=f(pause_ms + target), applied=target, iter_start=sim.now,
+        consec=0, ideal_next=None)
+    sim._load(j)
+    return True
 
 
 def position(j: dict, segs) -> float:

@@ -32,11 +32,28 @@ def small_cell(name: str) -> dict:
     return cell
 
 
+def small_open_cell(load: float = 0.9) -> dict:
+    """The churn cell's configuration cut to 8 racks at 2:1, its tenants
+    placed by a Themis host with 3 candidates on an open layout, fed by a
+    Poisson process from the same deck at ``load``."""
+    cell = small_cell("dense64-fine.churn")
+    cfg, mix = cell["config"], cell["mix"]
+    cfg["name"] = "small-open"
+    cfg["topology"]["oversubscription"] = 2.0
+    cfg["host"] = {"kind": "themis", "num_candidates": 3, "seed": 0}
+    cfg["layout"] = {"kind": "open"}
+    mix.update(process="poisson", load=load, min_workers=1, block=8,
+               warmup_rounds=6)
+    mix["check"] = dict(mix["check"], fluid_groups=2)
+    return cell
+
+
 _COUNTER = None
 
 
 def run_small(name: str, seed: int = 2**31 + 7, seconds: float = 1.5,
-              trace: bool = False, control: bool = False) -> dict:
+              trace: bool = False, control: bool = False,
+              cell: dict | None = None) -> dict:
     """One run of the small cell on this process's first JAX device,
     without the harness's look for a chip.  The rotation search takes the
     host's numpy path (bit-identical to the kernels, which the CPU would
@@ -52,7 +69,7 @@ def run_small(name: str, seed: int = 2**31 + 7, seconds: float = 1.5,
     compat._kernel_eligible = lambda backend, num_angles: False
     bench.warm_kernels = lambda cfg, mix: 0
     try:
-        return bench.run_cell(small_cell(name), seed, seconds, trace,
+        return bench.run_cell(cell or small_cell(name), seed, seconds, trace,
                               jax.devices()[0], time.perf_counter(), _COUNTER,
                               control=control)
     finally:

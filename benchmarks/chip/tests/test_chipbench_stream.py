@@ -1,6 +1,7 @@
 """The benchmark's traffic generator: seeded, endless, at its load."""
 
 import collections
+import hashlib
 import itertools
 import json
 import statistics
@@ -93,3 +94,117 @@ def test_churn_keeps_every_slot_full():
     departures = sum(1 for e in later if e[0] == "departure")
     span = later[-1][1] - later[0][1]
     assert departures / span == pytest.approx(len(slots) / mean_life, rel=0.1)
+
+
+# sha256 of the first 2,000 churn events of each seed, as the cell's bounds
+# were measured on them: a change to the stream changes what the bounds hold
+CHURN_SHA256 = {
+    1: "c29cbd1f5c6e338917a6604d41defbb422f0a714b01a47315d972af5184a8595",
+    2**31 + 12345: "0112693b0a3066b1def5b39a80ee21afd02149ed1469738d1ce6de6b9ceee922",
+    4052739537: "4aa32d0116cd2e464523ad73a85be3273ed115cd5021af46c577de91f200368a",
+}
+
+
+def _digest(events) -> str:
+    flat = [(k, t, (w.job_id, w.model, w.workers, w.iters, w.batch, w.placement)
+             if k == "arrival" else w) for k, t, w in events]
+    return hashlib.sha256(repr(flat).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("process", [None, "slots"], ids=["absent", "slots"])
+@pytest.mark.parametrize("seed", sorted(CHURN_SHA256))
+def test_churn_stream_is_unchanged(process, seed):
+    c, m = _cfg("dense64-fine"), _mix("churn")
+    if process is not None:
+        m["process"] = process
+    events = list(itertools.islice(stream.events(c, m, seed), 2000))
+    assert _digest(events) == CHURN_SHA256[seed]
+
+
+def test_every_seed_runs_the_same_tenants_elsewhere():
+    c, m = _cfg("dense64-fine"), _mix("churn")
+
+    def run(seed):
+        ev = list(itertools.islice(stream.events(c, m, seed), 2000))
+        work = [(k, t, (w.model, w.workers, w.batch, w.iters) if k == "arrival"
+                 else None) for k, t, w in ev]
+        return work, [(w.job_id[:4], w.placement) for k, _, w in ev if k == "arrival"]
+
+    (work_a, where_a), (work_b, where_b) = run(3), run(2**33 + 1)
+    assert work_a == work_b
+    assert where_a != where_b
+    # each seed starts one tenant on every slot
+    assert len({slot for slot, _ in where_a[:51]}) == 51
+    assert len({slot for slot, _ in where_b[:51]}) == 51
+    # and the same sequences of tenants share a hub rack
+    slots = stream.slot_layout(c)
+    spr = c["topology"]["servers_per_rack"]
+
+    def sharing(where):
+        racks = collections.defaultdict(set)
+        for k, s in enumerate(where):
+            racks[slots[s][0] // spr].add(k)
+        return sorted(map(sorted, racks.values()))
+
+    assert sharing(stream._places(c, 3)) == sharing(stream._places(c, 2**33 + 1))
+
+
+def _open_mix(process, **kw):
+    m = _mix("churn")
+    m.update(process=process, load=0.9, min_workers=1, max_workers=12, deck=256,
+             block=8, **kw)
+    return m
+
+
+@pytest.mark.parametrize("process,kw", [("poisson", {}), ("burst", {"burst": 4})])
+def test_every_seed_offers_the_same_blocks(process, kw):
+    c, m = _cfg("dense64-fine"), _open_mix(process, **kw)
+    n = 400 * m["block"]
+    a = list(itertools.islice(stream.events(c, m, 7), n))
+    b = list(itertools.islice(stream.events(c, m, 2**33 + 1), n))
+    assert a != b
+
+    def cards(events):
+        return collections.Counter((s.model, s.workers, s.batch, s.iters)
+                                   for _, _, s in events)
+
+    for i in range(0, n, m["block"]):
+        assert cards(a[i:i + m["block"]]) == cards(b[i:i + m["block"]])
+        assert a[i][1] == pytest.approx(b[i][1], rel=1e-9)
+
+
+def _offered_load(c, events) -> float:
+    """Solo GPU-milliseconds of the arrivals over the cluster's GPU time
+    up to the last of them."""
+    topo = c["topology"]
+    gpus = topo["racks"] * topo["servers_per_rack"] * topo["gpus_per_server"]
+    busy = sum(s.workers * s.iters
+               * stream.iter_time_ms(c["models"][s.model], s.workers, s.batch)
+               for _, _, s in events)
+    return busy / (gpus * events[-1][1])
+
+
+@pytest.mark.parametrize("seed", [7, 2**33 + 1])
+def test_poisson_stream_is_seeded_at_its_load(seed):
+    c, m = _cfg("dense64-fine"), _open_mix("poisson")
+    events = list(itertools.islice(stream.events(c, m, seed), 20000))
+    assert events[:300] == list(itertools.islice(stream.events(c, m, seed), 300))
+    assert events[:300] != list(itertools.islice(stream.events(c, m, seed + 1), 300))
+    assert all(k == "arrival" and s.placement is None for k, _, s in events)
+    times = [t for _, t, _ in events]
+    assert times == sorted(times) and len(set(times)) == len(times)
+    assert _offered_load(c, events) == pytest.approx(m["load"], rel=0.03)
+
+
+def test_burst_stream_lands_in_bursts_at_the_same_load():
+    c, m = _cfg("dense64-fine"), _open_mix("burst", burst=4)
+    events = list(itertools.islice(stream.events(c, m, 2**31 + 3), 20000))
+    per_instant = collections.Counter(t for _, t, _ in events)
+    assert set(per_instant.values()) == {4}
+    assert all(k == "arrival" and s.placement is None for k, _, s in events)
+    assert _offered_load(c, events) == pytest.approx(m["load"], rel=0.03)
+
+
+def test_unknown_process_raises():
+    with pytest.raises(ValueError):
+        stream.events(_cfg("dense64-fine"), _open_mix("diurnal"), 1)
