@@ -30,12 +30,14 @@ Two engines share these semantics bit for bit:
     link state lives in numpy arrays keyed by the job×link incidence the
     topology precomputes at ``configure`` (never per event); the max-min
     allocation + ECN marking are solved with vectorized water-filling once
-    per *distinct comm-competing set* and cached (segment transitions of
-    compute-only jobs hit the cache), and ``advance`` steps every job's
-    delay/remaining/marks with batched array updates.  Every float is
-    produced by the same IEEE operation in the same order as the scalar
-    loop, so rates, event sequences and ``Metrics.summary()`` are
-    *identical* — not merely close (tests/test_fluid_vectorized.py).
+    per *distinct comm-competing set of one connected component* of the
+    running jobs and cached (segment transitions of compute-only jobs, and
+    of jobs in other components, hit the cache), and ``advance`` steps
+    every job's delay/remaining/marks with batched array updates.  Both
+    engines fill each component on its own; every float is produced by the
+    same IEEE operation in the same order as the scalar loop, so rates,
+    event sequences and ``Metrics.summary()`` are *identical* — not merely
+    close (tests/test_fluid_vectorized.py).
 """
 
 from __future__ import annotations
@@ -58,8 +60,9 @@ from repro.core.circle import CommPattern
 __all__ = ["Segment", "segments_from_pattern", "FluidNetworkSim"]
 
 # Distinct comm-competing sets cached between two ``configure`` calls are
-# bounded in practice (jobs cycle through few segments); this cap only
-# guards pathological drift from unbounded memory growth.
+# bounded in practice (jobs cycle through few segments); this cap (on the
+# whole-fabric cache and on the per-component cache alike) only guards
+# pathological drift from unbounded memory growth.
 _ALLOC_CACHE_MAX = 4096
 
 # Delta solves between from-scratch rebuilds of the incremental solver's
@@ -81,6 +84,23 @@ class Segment:
     @property
     def gbits(self) -> float:
         return self.gbps * self.duration_ms * 1e-3
+
+
+def _lru_get(cache: dict, key):
+    """Look ``key`` up; a hit moves to the dict's tail (most recent)."""
+    hit = cache.pop(key, None)
+    if hit is not None:
+        cache[key] = hit
+    return hit
+
+
+def _lru_put(cache: dict, key, value) -> None:
+    """Insert after evicting only least-recently-used entries — a cold
+    scan of fresh keys (256+-rack churn) must not wipe the hot working
+    set."""
+    while len(cache) >= _ALLOC_CACHE_MAX:
+        del cache[next(iter(cache))]
+    cache[key] = value
 
 
 def segments_from_pattern(pattern: CommPattern) -> list[Segment]:
@@ -174,6 +194,34 @@ class _JobExec:
         return self.segments[self.seg_idx].gbps
 
 
+@dataclass
+class _Components:
+    """Connected components of the job × link graph over every running
+    job, with every link its ring crosses (comm or compute, binding or
+    not): max-min fairness separates exactly over them, since two
+    components share no link.  Rebuilt when the running set or a job's
+    links change, never per event.
+
+    ``jobs`` lists each component's job ids in ``_execs`` order (what the
+    scalar oracle fills by).  The vectorized engine adds, over the slot
+    axis: each component's ``slots`` (ascending) and their bytes
+    (``idents``, the component part of its cache key); ``of``, the
+    component of every slot (-1: dead); ``label``, the smallest slot of
+    its component; and the assembled allocation — ``rates``/``marks`` of
+    every component as last solved, with ``code`` (per slot: segment
+    index + 1 when in the comm set, else 0) the state they describe.
+    """
+
+    jobs: list[list[str]]
+    slots: list[np.ndarray] | None = None
+    idents: list[bytes] | None = None
+    of: np.ndarray | None = None
+    label: np.ndarray | None = None
+    code: np.ndarray | None = None
+    rates: np.ndarray | None = None
+    marks: np.ndarray | None = None
+
+
 class FluidNetworkSim:
     """Exact event-driven fluid simulation of jobs sharing the fabric."""
 
@@ -230,6 +278,10 @@ class FluidNetworkSim:
         # were answered from the cache (serve-mode telemetry)
         self.alloc_solves: int = 0
         self.alloc_hits: int = 0
+        # the same for one connected component's solve, looked up on a
+        # whole-fabric miss for each component whose comm set changed
+        self.alloc_comp_solves: int = 0
+        self.alloc_comp_hits: int = 0
         # optional psim-style per-link load telemetry (repro.cluster
         # .linkload): None costs nothing; attach_link_recorder wires one
         # into the vectorized event loop
@@ -247,7 +299,10 @@ class FluidNetworkSim:
         self._slots: list[_JobExec] = []
         self._slot_of: dict[str, int] = {}
         self._inc: LinkIncidence | None = None
-        self._alloc_cache: dict[bytes, tuple[np.ndarray, np.ndarray]] = {}
+        self._alloc_cache: dict[tuple, tuple[np.ndarray, ...]] = {}
+        self._comp_cache: dict[bytes, tuple[np.ndarray, np.ndarray]] = {}
+        # partition of the running jobs (None: stale, rebuilt on next use)
+        self._comps: _Components | None = None
         # while a fluid/advance span is open: host ns spent solving its
         # alloc-cache misses (None otherwise, so the miss path stays as is)
         self._solve_ns: int | None = None
@@ -355,6 +410,7 @@ class FluidNetworkSim:
             self._admit(job, self.now_ms)
             new[job.job_id] = ex
         self._execs = new
+        self._comps = None
         if self.vectorized:
             self._build_arrays()
 
@@ -363,22 +419,26 @@ class FluidNetworkSim:
     # n-1 keep running; rebuilding every array (and discarding the
     # water-filling cache) per event is what makes batch reconfiguration
     # O(cluster) per arrival.  The delta ops below touch only the affected
-    # slot and *keep* the allocation cache, which stays sound because a
-    # cache key is (comm-membership bytes, per-member segment bytes) over
-    # the current slot axis:
+    # slot and *keep* the allocation caches, which stay sound because a
+    # whole-fabric key is (comm-membership bytes, per-member segment bytes,
+    # per-member component label bytes) over the current slot axis, and a
+    # component key is (component slot bytes, per-slot comm/segment code
+    # bytes) — each a pure function of the rates it maps to:
     #
     #   * ``remove_job`` only clears the slot's alive bit — keys where the
     #     slot was a comm member can never be produced again, keys where
-    #     it was not remain exactly as valid;
-    #   * ``add_job`` appends a slot, so every new key's membership mask is
-    #     one byte longer — old entries become unreachable (never wrong),
-    #     since a (mask, int32-segments) encoding can never collide with
-    #     one whose mask length differs by 1 (4·k' − 4·k = 1 is unsolvable);
+    #     it was not remain exactly as valid: a component it leaves gets a
+    #     new slot set, and a whole-fabric key still names how its members
+    #     group into solved components;
+    #   * ``add_job`` appends a slot, so every new whole-fabric key's
+    #     membership mask is one byte longer — old entries become
+    #     unreachable (never wrong; the key is a tuple, so encodings of
+    #     different lengths cannot collide);
     #   * ``update_job`` with an unchanged placement alters only
     #     delay/alignment state, which enters the solve through the
     #     membership mask itself; a changed placement (in-place migration)
     #     rewrites the slot's link columns, which ARE invisible to the key —
-    #     that one case clears the cache.
+    #     that one case clears the caches.
     #
     # Dead slots accumulated by departures are compacted (full rebuild)
     # once they outnumber the live ones, bounding memory.
@@ -393,6 +453,7 @@ class FluidNetworkSim:
         ex = self._exec_for(job)
         self._admit(job, self.now_ms)
         self._execs[job.job_id] = ex
+        self._comps = None
         if not self.vectorized:
             return
         live = int(np.count_nonzero(self._alive))
@@ -422,6 +483,7 @@ class FluidNetworkSim:
             ex = self._execs.pop(job_id)
         except KeyError:
             raise UnknownJobError(job_id, self._execs) from None
+        self._comps = None
         if self.vectorized:
             self._alive[self._slot_of.pop(job_id)] = False
         return ex.job
@@ -440,6 +502,8 @@ class FluidNetworkSim:
         resized = ex.segments != old.segments
         self._admit(job, self.now_ms)
         self._execs[job.job_id] = ex  # overwrite keeps dict position
+        if migrated:
+            self._comps = None
         if not self.vectorized:
             return
         i = self._slot_of[job.job_id]
@@ -454,9 +518,9 @@ class FluidNetworkSim:
             self._inc = self._inc.replace_row(i, cols)
         if migrated or resized:
             # either way the cached rates no longer describe this slot:
-            # drop the cache (and the incremental solver's per-link
-            # demand/live state with it)
-            self._alloc_cache.clear()
+            # drop the caches (and the incremental solver's per-link
+            # demand/live state with them)
+            self._drop_alloc_caches()
             self._wf = None
 
     def configure_incremental(self, jobs: list[Job]) -> str:
@@ -499,15 +563,15 @@ class FluidNetworkSim:
 
         The primitive behind ``LinkDown`` (0.0) / ``LinkDegrade`` /
         ``LinkRecover``.  Capacities are deliberately not part of the
-        allocation-cache key (they never changed mid-run before faults
-        existed), so the cache is dropped; the solvers read capacities
+        allocation-cache keys (they never changed mid-run before faults
+        existed), so the caches are dropped; the solvers read capacities
         live, so the next solve — scalar, vectorized, or incremental —
         sees the new value.  The incremental water-filling state is kept:
         the link id is marked dirty and the next delta solve re-fills
         exactly the components the change touches.
         """
         old = self.topo.set_link_capacity(name, gbps)
-        self._alloc_cache.clear()
+        self._drop_alloc_caches()
         if self.incremental:
             self._wf_cap_dirty.add(self.topo.link_ids[name])
         return old
@@ -549,7 +613,8 @@ class FluidNetworkSim:
         respecting per-segment demand caps (progressive filling).
 
         Dispatches to the cached vectorized solve or the scalar oracle;
-        both return the same dict, bit for bit."""
+        both fill each connected component on its own and return the same
+        dict, bit for bit."""
         if self.vectorized:
             comm_mask = self._comm_mask(self._cutoff_mask())
             rates, _, _ = self._cached_solve(comm_mask)
@@ -573,11 +638,24 @@ class FluidNetworkSim:
     # ---------------------- scalar oracle ------------------------- #
     def _allocate_scalar(self) -> dict[str, float]:
         """The original per-event progressive-filling loop (the oracle the
-        vectorized water-filling is equivalence-tested against)."""
+        vectorized water-filling is equivalence-tested against), run over
+        each connected component's comm jobs in turn — the unit the
+        vectorized engine solves and caches, so both round alike."""
         comm = self._comm_jobs()
         rates = {jid: 0.0 for jid in comm}
         if not comm:
             return rates
+        for part in self._components().jobs:
+            sub = {jid: comm[jid] for jid in part if jid in comm}
+            if sub:
+                self._fill_scalar(sub, rates)
+        return rates
+
+    def _fill_scalar(
+        self, comm: dict[str, _JobExec], rates: dict[str, float]
+    ) -> None:
+        """Progressive filling of one component's comm jobs into
+        ``rates`` (zero on entry for each of them)."""
         remaining = {}
         users: dict[str, list[str]] = {}
         demand: dict[str, float] = {}
@@ -616,7 +694,6 @@ class FluidNetworkSim:
             if not newly_frozen:
                 break
             unfrozen -= newly_frozen
-        return rates
 
     def _mark_rates_scalar(self) -> dict[str, float]:
         """ECN marks per ms for each job (demand-over-capacity model)."""
@@ -667,8 +744,64 @@ class FluidNetworkSim:
         self._alive = np.ones(n, dtype=bool)
         for i, ex in enumerate(self._slots):
             self._sync_seg(i, ex)
-        self._alloc_cache.clear()
+        self._drop_alloc_caches()
         self._wf = None
+
+    def _drop_alloc_caches(self) -> None:
+        """Forget every solved allocation (the rates a key maps to have
+        changed under it), and the assembled one with the partition."""
+        self._alloc_cache.clear()
+        self._comp_cache.clear()
+        self._comps = None
+
+    def _components(self) -> _Components:
+        """The running jobs' partition, rebuilt if stale."""
+        if self._comps is None:
+            self._comps = self._partition()
+        return self._comps
+
+    def _partition(self) -> _Components:
+        """Components by a walk over shared links, in ``_execs`` order."""
+        users: dict[str, list[str]] = {}
+        for jid, ex in self._execs.items():
+            for l in ex.links:
+                users.setdefault(l.name, []).append(jid)
+        comp_of: dict[str, int] = {}
+        n_comps = 0
+        for jid in self._execs:
+            if jid in comp_of:
+                continue
+            comp_of[jid] = n_comps
+            stack = [jid]
+            while stack:
+                for l in self._execs[stack.pop()].links:
+                    for v in users.pop(l.name, ()):
+                        if v not in comp_of:
+                            comp_of[v] = n_comps
+                            stack.append(v)
+            n_comps += 1
+        jobs: list[list[str]] = [[] for _ in range(n_comps)]
+        for jid in self._execs:
+            jobs[comp_of[jid]].append(jid)
+        comps = _Components(jobs)
+        if not self.vectorized:
+            return comps
+        n = len(self._slots)
+        comps.slots = [
+            np.array([self._slot_of[j] for j in js], dtype=np.int64)
+            for js in jobs
+        ]
+        comps.idents = [sl.tobytes() for sl in comps.slots]
+        comps.of = np.full(n, -1, dtype=np.int64)
+        comps.label = np.full(n, -1, dtype=np.int32)
+        for c, sl in enumerate(comps.slots):
+            comps.of[sl] = c
+            comps.label[sl] = sl[0]
+        # -1 matches no state: every component is solved or looked up once
+        comps.code = np.where(self._alive, -1, 0).astype(np.int32)
+        comps.rates = np.zeros(n, dtype=np.float64)
+        comps.marks = np.zeros(n, dtype=np.float64)
+        return comps
 
     def _sync_seg(self, i: int, ex: _JobExec) -> None:
         """Refresh slot ``i``'s segment-derived columns (on transition)."""
@@ -703,36 +836,68 @@ class FluidNetworkSim:
         """(rates, mark rates, rate>0 mask) for the comm-competing set.
 
         Keyed on (membership, per-member segment): the allocation is a
-        pure function of *which jobs communicate with which demand cap*,
-        so anything else — compute-only jobs advancing through their own
-        segments, delays draining, time passing — hits the cache and the
-        per-event cost collapses to one dict lookup.
+        pure function of *which jobs communicate with which demand cap*
+        (and, per connected component solved, of which members share a
+        component), so anything else — compute-only jobs advancing
+        through their own segments, delays draining, time passing — hits
+        the cache and the per-event cost collapses to one dict lookup.  A
+        miss re-assembles the allocation per component
+        (:meth:`_solve_components`); the incremental engine keeps its own
+        whole-fabric delta solve.
         """
-        key = comm_mask.tobytes() + self._segi[comm_mask].tobytes()
-        hit = self._alloc_cache.get(key)
+        key = (comm_mask.tobytes(), self._segi[comm_mask].tobytes())
+        if not self.incremental:
+            key += (self._components().label[comm_mask].tobytes(),)
+        hit = _lru_get(self._alloc_cache, key)
         if hit is not None:
             self.alloc_hits += 1
-            # LRU touch: re-insertion moves the key to the dict's tail, so
-            # eviction below always removes the least-recently-used entry
-            self._alloc_cache[key] = self._alloc_cache.pop(key)
+            return hit
+        timed = self._solve_ns is not None
+        if timed:
+            t0 = time.perf_counter_ns()
+        if self.incremental:
+            rates, marks = self._solve_alloc_incremental(comm_mask)
         else:
-            while len(self._alloc_cache) >= _ALLOC_CACHE_MAX:
-                # evict only the LRU entry — a cold scan of fresh comm-sets
-                # (256+-rack churn) must not wipe the hot working set
-                del self._alloc_cache[next(iter(self._alloc_cache))]
-            timed = self._solve_ns is not None
-            if timed:
-                t0 = time.perf_counter_ns()
-            if self.incremental:
-                rates, marks = self._solve_alloc_incremental(comm_mask)
-            else:
-                rates, marks = self._solve_alloc(comm_mask)
-            if timed:
-                self._solve_ns += time.perf_counter_ns() - t0
-            hit = (rates, marks, rates > _EPS)
-            self._alloc_cache[key] = hit
-            self.alloc_solves += 1
+            rates, marks = self._solve_components(comm_mask)
+        if timed:
+            self._solve_ns += time.perf_counter_ns() - t0
+        hit = (rates, marks, rates > _EPS)
+        _lru_put(self._alloc_cache, key, hit)
+        self.alloc_solves += 1
         return hit
+
+    def _solve_components(
+        self, comm_mask: np.ndarray
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Rates and mark rates assembled per connected component.
+
+        Only components whose comm membership or segments changed since
+        the last assembly are looked up; each is keyed on its slots and
+        their (comm, segment) code, and a miss solves its comm jobs alone
+        with :meth:`_solve_alloc`.  A link's comm users all sit in one
+        component, so marks (per link) are the whole-fabric solve's floats;
+        rates are the oracle's, which fills the same components.
+        """
+        cs = self._components()
+        code = self._segi + 1
+        code *= comm_mask
+        changed = np.nonzero(code != cs.code)[0]
+        for c in set(cs.of[changed].tolist()):
+            sl = cs.slots[c]
+            key = cs.idents[c] + code[sl].tobytes()
+            hit = _lru_get(self._comp_cache, key)
+            if hit is not None:
+                self.alloc_comp_hits += 1
+            else:
+                sub = np.zeros(len(self._slots), dtype=bool)
+                sub[sl] = comm_mask[sl]
+                rates, marks = self._solve_alloc(sub)
+                hit = (rates[sl], marks[sl])
+                _lru_put(self._comp_cache, key, hit)
+                self.alloc_comp_solves += 1
+            cs.rates[sl], cs.marks[sl] = hit
+        cs.code = code
+        return cs.rates.copy(), cs.marks.copy()
 
     def _solve_alloc(self, comm_mask: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
         """Vectorized water-filling + ECN marking over (jobs, links) arrays.
@@ -1329,11 +1494,15 @@ class FluidNetworkSim:
 
         While spans are on (:mod:`repro.spans`), each call records one
         ``fluid/advance`` span: ``events`` (loop steps), ``solves``
-        (alloc-cache misses) and ``solve_ns`` (host ns solving them).
+        (alloc-cache misses), ``solve_ns`` (host ns answering them:
+        component lookups and solves), ``comp_hits`` and ``comp_solves``
+        (component lookups answered from the component cache, and
+        solved).
         """
         if not spans.enabled():
             return self._advance(until_ms, max_events)
         solves0 = self.alloc_solves
+        comp_hits0, comp_solves0 = self.alloc_comp_hits, self.alloc_comp_solves
         with spans.span("fluid/advance") as sp:
             self._solve_ns = 0
             try:
@@ -1341,7 +1510,9 @@ class FluidNetworkSim:
             finally:
                 sp.set(events=self._last_events,
                        solves=self.alloc_solves - solves0,
-                       solve_ns=self._solve_ns)
+                       solve_ns=self._solve_ns,
+                       comp_hits=self.alloc_comp_hits - comp_hits0,
+                       comp_solves=self.alloc_comp_solves - comp_solves0)
                 self._solve_ns = None
 
     def _advance(self, until_ms: float, max_events: int) -> list[Job]:
@@ -1433,6 +1604,7 @@ class FluidNetworkSim:
                             del self._execs[ex.job.job_id]
                             self._slot_of.pop(ex.job.job_id, None)
                             self._alive[i] = False
+                            self._comps = None
                             finished.append(ex.job)
                 if finished:
                     break
@@ -1489,6 +1661,7 @@ class FluidNetworkSim:
                         ex.job.finish_ms = self.now_ms
                         ex.job.state = JobState.DONE
                         del self._execs[jid]
+                        self._comps = None
                         finished.append(ex.job)
             if finished:
                 break

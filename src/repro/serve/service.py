@@ -265,6 +265,8 @@ class SchedulerService:
         try:
             out["alloc_cache_solves"] = float(self.net.alloc_solves)
             out["alloc_cache_hits"] = float(self.net.alloc_hits)
+            out["alloc_cache_comp_solves"] = float(self.net.alloc_comp_solves)
+            out["alloc_cache_comp_hits"] = float(self.net.alloc_comp_hits)
         except Exception:  # pragma: no cover - defensive
             pass
         try:

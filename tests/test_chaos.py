@@ -559,3 +559,5 @@ class TestTelemetryUnderFire:
         assert tel["degraded_decisions"] == 0.0
         assert tel["decisions"] == 0.0
         assert "alloc_cache_solves" not in tel  # degraded to fewer keys
+        assert "alloc_cache_comp_solves" not in tel
+        assert "alloc_cache_comp_hits" not in tel

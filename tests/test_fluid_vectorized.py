@@ -16,6 +16,7 @@ pin that contract:
     a 64-rack fabric through the vectorized path.
 """
 
+import copy
 import random
 
 import numpy as np
@@ -337,3 +338,253 @@ def test_cutoff_releases_share_vectorized_64rack():
     post = survivor.iter_times_ms[recorded + 2:]
     assert post, "survivor must keep iterating after the cutoff"
     assert sum(post) / len(post) == pytest.approx(survivor.solo_iter_ms, rel=0.02)
+
+
+# ------------------------------------------------------------------ #
+# per-component water-filling
+# ------------------------------------------------------------------ #
+def _hub_fabric():
+    """16 racks of 4 servers at 4:1 (one uplink per rack), NICs
+    alternating 50/100 Gbps."""
+    return Topology(
+        num_racks=16,
+        servers_per_rack=4,
+        nic_gbps=50.0,
+        rack_nic_gbps=tuple(100.0 if r % 2 else 50.0 for r in range(16)),
+        oversubscription=4.0,
+    )
+
+
+def _hub_server(group, k, leaf=False):
+    """Server ``k`` of group ``group``'s hub rack (4g+1) or leaf rack
+    (4g+3): both 100 Gbps, so a 3-tenant group shares 88 Gbps three ways
+    while another group's 15 Gbps caps freeze first — a split the
+    whole-fabric fill rounds differently from the group's own."""
+    return 4 * (4 * group + (3 if leaf else 1)) + k
+
+
+def _hub_jobs(groups=4, per_group=3, iters=400, tag="hub"):
+    """``per_group`` tenants in each of ``groups`` hub groups: tenant k
+    holds server k of its group's hub and leaf racks, so the tenants of a
+    group share its two uplinks and no link with another group."""
+    models = [MODELS[i % len(MODELS)] for i in range(groups * per_group)]
+    jobs = snapshot_trace(
+        [(m, 2, 1400 if m == "vgg19" else 8) for m in models], iters=iters
+    )
+    for i, j in enumerate(jobs):
+        g, k = divmod(i, per_group)
+        j.job_id = f"{tag}{i}-{j.model}"
+        j.placement = (_hub_server(g, k), _hub_server(g, k, leaf=True))
+        j.state = JobState.RUNNING
+    return jobs
+
+
+def _assert_alloc_equal(sim_v, sim_s):
+    assert sim_v._allocate() == sim_s._allocate()
+    assert sim_v._mark_rates() == sim_s._mark_rates()
+
+
+def test_components_follow_hub_groups():
+    topo = _hub_fabric()
+    sim = FluidNetworkSim(topo)
+    sim.configure(_hub_jobs())
+    parts = sim._components().jobs
+    assert [len(p) for p in parts] == [3, 3, 3, 3]
+    # a tenant bridging group 0's hub and group 3's leaf rack joins them
+    bridge = _hub_jobs(groups=1, per_group=1, tag="bridge")[0]
+    bridge.placement = (_hub_server(0, 3), _hub_server(3, 3, leaf=True))
+    sim.add_job(bridge)
+    parts = sim._components().jobs
+    assert sorted(len(p) for p in parts) == [3, 3, 7]
+    # each component lists its jobs in running (slot) order
+    order = list(sim._execs)
+    for p in parts:
+        assert p == sorted(p, key=order.index)
+    sim.remove_job(bridge.job_id)
+    assert [len(p) for p in sim._components().jobs] == [3, 3, 3, 3]
+
+
+def test_component_engine_matches_component_oracle_under_churn():
+    """Vectorized per-component fills and the scalar oracle's per-component
+    loop agree bit for bit through arrivals, departures and a tenant that
+    joins two groups into one component and leaves again."""
+    sims, populations = [], []
+    for vectorized in (True, False):
+        topo = _hub_fabric()
+        jobs = _hub_jobs()
+        sim = FluidNetworkSim(topo, vectorized=vectorized, seed=5)
+        assert sim.configure_incremental(list(jobs)) == "delta"
+        sims.append(sim)
+        populations.append(jobs)
+    sim_v, sim_s = sims
+    arrivals = [_hub_jobs(groups=1, per_group=2, tag="new") for _ in sims]
+    solve = sim_v._cached_solve
+    split = 0
+
+    def counting(mask):
+        # how often filling the whole comm set at once would round
+        # otherwise: the parity below then holds only per component
+        nonlocal split
+        rates, marks, pos = solve(mask)
+        split += not np.array_equal(rates, sim_v._solve_alloc(mask)[0])
+        return rates, marks, pos
+
+    sim_v._cached_solve = counting
+
+    def step(ms):
+        for sim in sims:
+            sim.advance(sim.now_ms + ms)
+        assert sim_v.now_ms == sim_s.now_ms
+        _assert_alloc_equal(sim_v, sim_s)
+
+    def reconfigure(edit):
+        for side, (sim, jobs) in enumerate(zip(sims, populations)):
+            edit(side, jobs)
+            sim.configure_incremental(list(jobs))
+        _assert_alloc_equal(sim_v, sim_s)
+
+    _assert_alloc_equal(sim_v, sim_s)
+    step(700.0)
+    # a departure from group 1, an arrival into group 2
+    reconfigure(lambda side, jobs: jobs.pop(4))
+    step(900.0)
+
+    def arrive(side, jobs):
+        new = arrivals[side][0]
+        new.placement = (_hub_server(2, 3), _hub_server(2, 3, leaf=True))
+        jobs.append(new)
+
+    reconfigure(arrive)
+    step(1100.0)
+
+    # a bridge from group 0's hub to group 3's leaf merges two components
+    def bridge(side, jobs):
+        new = arrivals[side][1]
+        new.placement = (_hub_server(0, 3), _hub_server(3, 3, leaf=True))
+        jobs.append(new)
+
+    reconfigure(bridge)
+    assert len(sim_v._components().jobs) == 3
+    step(1300.0)
+    reconfigure(lambda side, jobs: jobs.pop())
+    assert len(sim_v._components().jobs) == 4
+    step(2000.0)
+    assert sim_v.alloc_comp_hits > 0 and sim_v.alloc_comp_solves > 0
+    assert split > 0
+    for jv, js in zip(*populations):
+        assert jv.iter_times_ms == js.iter_times_ms
+        assert jv.ecn_marks == js.ecn_marks
+        assert jv.iters_done == js.iters_done > 0
+
+
+def _all_comm(sim):
+    mask = sim._alive.copy()
+    mask &= np.array([bool(ex.links) for ex in sim._slots])
+    return mask
+
+
+def test_segment_change_resolves_only_its_component():
+    """A change in one component's comm set costs at most one component
+    solve; every other component keeps its solved rates untouched, and a
+    component returning to a seen state hits the component cache."""
+    sim = FluidNetworkSim(_hub_fabric())
+    sim.configure(_hub_jobs())
+    comps = sim._components()
+    g0, g1 = comps.slots[0], comps.slots[1]
+    full = _all_comm(sim)
+    r_full, m_full, _ = sim._cached_solve(full)
+    assert sim.alloc_comp_solves == 4
+
+    def solve(mask):
+        solves, hits = sim.alloc_comp_solves, sim.alloc_comp_hits
+        rates, marks, _ = sim._cached_solve(mask)
+        return rates, marks, sim.alloc_comp_solves - solves, sim.alloc_comp_hits - hits
+
+    off0 = full.copy()
+    off0[g0[0]] = False
+    r, m, solved, hit = solve(off0)
+    assert (solved, hit) == (1, 0)
+    rest = np.setdiff1d(np.arange(len(full)), g0)
+    assert np.array_equal(r[rest], r_full[rest])
+    assert np.array_equal(m[rest], m_full[rest])
+    # the component's own rates are its comm jobs solved alone
+    sub = np.zeros_like(off0)
+    sub[g0] = off0[g0]
+    alone_r, alone_m = sim._solve_alloc(sub)
+    assert np.array_equal(r[g0], alone_r[g0])
+    assert np.array_equal(m[g0], alone_m[g0])
+
+    off1 = full.copy()
+    off1[g1[0]] = False            # group 0 back, group 1 changed
+    r, m, solved, hit = solve(off1)
+    assert (solved, hit) == (1, 1)
+    assert np.array_equal(r[g0], r_full[g0])
+    assert sim.alloc_solves == 3 and sim.alloc_hits == 0
+    solve(off1)                    # the whole-fabric key answers first
+    assert sim.alloc_hits == 1
+
+
+def test_single_component_matches_whole_set_solve():
+    """Where every running job shares one hub uplink, the per-component
+    engine is the whole-fabric solve, bit for bit, at every event."""
+    topo = _hub_fabric()
+    jobs = _hub_jobs(groups=1, per_group=4)
+    for k, j in enumerate(jobs):   # one hub rack, a leaf rack each
+        j.placement = (k, 4 * (k + 1), 4 * (k + 1) + 1)
+    sim = FluidNetworkSim(topo)
+    sim.configure(jobs)
+    assert len(sim._components().jobs) == 1
+    solve = sim._cached_solve
+    checked = 0
+
+    def checking(mask):
+        nonlocal checked
+        rates, marks, pos = solve(mask)
+        whole_r, whole_m = sim._solve_alloc(mask)
+        assert np.array_equal(rates, whole_r)
+        assert np.array_equal(marks, whole_m)
+        checked += 1
+        return rates, marks, pos
+
+    sim._cached_solve = checking
+    sim.advance(20_000.0)
+    assert checked > 100 and sim.alloc_solves > 1
+
+
+@pytest.mark.parametrize("change", ["migrate", "resize", "capacity"])
+def test_cache_invalidation_forces_component_resolve(change):
+    """A migrated or resized ``update_job`` and ``set_link_capacity`` change
+    what a component key maps to: both caches drop, every component is
+    solved again, and the rates are a fresh engine's."""
+    topo = _hub_fabric()
+    jobs = _hub_jobs()
+    sim = FluidNetworkSim(topo)
+    sim.configure_incremental(list(jobs))
+    mask = _all_comm(sim)
+    sim._cached_solve(mask)
+    solves = sim.alloc_comp_solves
+    sim._cached_solve(mask)
+    assert sim.alloc_comp_solves == solves   # warm: nothing re-solved
+    if change == "migrate":
+        # group 0's tenant onto group 3's leaf rack
+        jobs[1].placement = (_hub_server(0, 1), _hub_server(3, 1, leaf=True))
+        sim.configure_incremental(list(jobs))
+    elif change == "resize":
+        jobs[1].num_workers = 3
+        jobs[1].placement = (
+            _hub_server(0, 1), _hub_server(0, 1, leaf=True),
+            _hub_server(0, 2, leaf=True),
+        )
+        sim.configure_incremental(list(jobs))
+    else:
+        sim.set_link_capacity(sim._execs[jobs[0].job_id].links[1].name, 25.0)
+    assert not sim._comp_cache and not sim._alloc_cache
+    mask = _all_comm(sim)
+    rates, marks, _ = sim._cached_solve(mask)
+    n_comps = len(sim._components().jobs)
+    assert sim.alloc_comp_solves == solves + n_comps
+    fresh = FluidNetworkSim(topo)
+    fresh.configure(copy.deepcopy(jobs))
+    want_r, want_m, _ = fresh._cached_solve(mask)
+    assert np.array_equal(rates, want_r)
+    assert np.array_equal(marks, want_m)

@@ -70,6 +70,10 @@ class TestGoldenEquivalence:
         # appends arrivals / drops departures — no survivor reordering)
         assert telemetry["configure_delta"] == len(d_serve)
         assert telemetry.get("configure_rebuild", 0.0) == 0.0
+        # whole-fabric misses are assembled from per-component lookups
+        assert telemetry["alloc_cache_solves"] > 0
+        assert telemetry["alloc_cache_comp_solves"] > 0
+        assert telemetry["alloc_cache_comp_hits"] >= 0
 
     def test_dynamic_arrivals_match_batch_themis_cassini(self):
         """Arrival/departure churn with a real host scheduler (Themis):

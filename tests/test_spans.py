@@ -5,6 +5,7 @@ kernel launches and the fluid engine."""
 from __future__ import annotations
 
 import threading
+import time
 import types
 
 import jax
@@ -173,3 +174,40 @@ def test_fluid_advance_counts_its_event_steps(traced):
     assert rec.attrs["solves"] == sim.alloc_solves - solves0 > 0
     assert 0 < rec.attrs["solve_ns"] <= rec.t1_ns - rec.t0_ns
     assert sim._solve_ns is None
+
+
+def test_fluid_advance_counts_component_lookups(traced):
+    """``comp_hits``/``comp_solves`` count the component lookups of the
+    alloc-cache misses, and ``solve_ns`` times the whole miss path: every
+    component lookup and solve falls inside it."""
+    jobs = snapshot_trace(
+        [("vgg19", 2, 1400), ("vgg19", 2, 1400), ("gpt2", 2, 8), ("bert", 2, 8)],
+        iters=200,
+    )
+    # two rack pairs, two tenants each: two independent components
+    for j, p in zip(jobs, [(0, 4), (1, 5), (8, 12), (9, 13)]):
+        j.placement = p
+        j.state = JobState.RUNNING
+    sim = FluidNetworkSim(Topology(num_racks=4, servers_per_rack=4,
+                                   oversubscription=4.0))
+    sim.configure(jobs)
+    assert len(sim._components().jobs) == 2
+    assemble = sim._solve_components
+    inside_ns = 0
+
+    def timed(mask):
+        nonlocal inside_ns
+        t0 = time.perf_counter_ns()
+        try:
+            return assemble(mask)
+        finally:
+            inside_ns += time.perf_counter_ns() - t0
+
+    sim._solve_components = timed
+    hits0, solves0 = sim.alloc_comp_hits, sim.alloc_comp_solves
+    sim.advance(30_000.0)
+    (rec,) = spans.records()
+    assert rec.attrs["comp_hits"] == sim.alloc_comp_hits - hits0 > 0
+    assert rec.attrs["comp_solves"] == sim.alloc_comp_solves - solves0 > 0
+    assert rec.attrs["solves"] > 0
+    assert inside_ns <= rec.attrs["solve_ns"] <= rec.t1_ns - rec.t0_ns
